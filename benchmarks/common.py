@@ -20,6 +20,20 @@ from repro.core.graph import CSRGraph
 from repro.data import powerlaw_cluster, rmat_graph, sbm_graph
 
 
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def enable_compile_cache() -> None:
+    """Keep JAX's persistent compile cache where ``JAX_COMPILATION_CACHE_DIR``
+    says (JAX reads it itself); without it, at the fixed ``<repo>/.jax_cache``,
+    since the cache directory is part of what a later run must find again."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        import jax
+
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(REPO_ROOT, ".jax_cache"))
+
+
 def graph_suite(small: bool = False) -> Dict[str, CSRGraph]:
     """Five graphs mirroring Table 1's families: web (R-MAT power-law),
     social (powerlaw-cluster), community-structured (SBM), road (2D grid),

@@ -15,15 +15,17 @@ Sections:
     refine  Leiden-style refinement vs plain Louvain (Q, wall time,
             disconnected-community audit)
     distdyn  sharded streaming updates/sec vs cold sharded recompute
-             (forced-8-device subprocess)
+             (in-process on a TPU's chips; a forced-8-device subprocess
+             on the CPU)
     fleet  multi-tenant serving fleet (sharded x batched) vs sequential
-           per-tenant sharded serving (forced-8-device subprocess)
+           per-tenant sharded serving (same placement as distdyn)
     roofline  achieved rates from the committed BENCH_*.json artifacts vs
               the paper's 560M edges/s headline
 
 Every section also writes a machine-readable ``BENCH_<name>.json`` (rows +
 wall seconds + backend), so the perf trajectory is diffable across PRs;
-``BENCH_OUT_DIR`` redirects the artifacts.
+``BENCH_OUT_DIR`` redirects the artifacts.  JAX's persistent compile cache
+lives where ``JAX_COMPILATION_CACHE_DIR`` says, else in ``<repo>/.jax_cache``.
 """
 
 from __future__ import annotations
@@ -81,8 +83,14 @@ def main() -> None:
     def want(name: str) -> bool:
         return only is None or name in only
 
-    from benchmarks.common import emit_json
+    import jax
 
+    from benchmarks.common import emit_json, enable_compile_cache
+
+    enable_compile_cache()
+    # A chip belongs to one process: on a TPU every section runs here, on
+    # the chips this process holds.
+    on_tpu = jax.default_backend() == "tpu"
     t0 = time.perf_counter()
     failed = False
 
@@ -93,6 +101,22 @@ def main() -> None:
         rows = fn()
         emit_json(name, rows, seconds=time.perf_counter() - t, small=small)
         print()
+
+    def forced_host_devices(name: str, module: str, title: str) -> bool:
+        """Run a sharded section in a subprocess that forces 8 CPU devices
+        before JAX initializes (it emits its BENCH json itself)."""
+        print(f"== {name}: {title} (8 forced host devices, subprocess) ==")
+        env = dict(os.environ)
+        env["PYTHONPATH"] = "src" + (
+            os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+        cmd = [sys.executable, "-m", module]
+        if not small:
+            cmd.append("--full")
+        proc = subprocess.run(cmd, env=env)
+        if proc.returncode != 0:
+            print(f"({name} subprocess failed with code {proc.returncode})")
+        print()
+        return proc.returncode != 0
 
     if want("fig3"):
         from benchmarks import bench_fig3_ablations
@@ -117,7 +141,7 @@ def main() -> None:
                                                    repeats=repeats))
     if want("fig8"):
         from benchmarks import bench_fig8_scaling
-        section("fig8", "strong scaling (structural, 1..8 host devices)",
+        section("fig8", "strong scaling (structural, 1..8 devices)",
                 lambda: bench_fig8_scaling.run(max_devices=8))
     if want("dynamic"):
         from benchmarks import bench_dynamic
@@ -138,38 +162,26 @@ def main() -> None:
                 "(Q / wall time / connectivity audit)",
                 lambda: bench_refine.run(small=small, repeats=repeats))
     if want("distdyn"):
-        print("== distdyn: sharded streaming vs cold sharded recompute "
-              "(8 forced host devices, subprocess) ==")
-        # The benchmark must force the device count before JAX initializes,
-        # so it runs as its own process (it emits BENCH_distdyn.json itself).
-        env = dict(os.environ)
-        env["PYTHONPATH"] = "src" + (
-            os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-        cmd = [sys.executable, "-m", "benchmarks.bench_distributed_dynamic"]
-        if not small:
-            cmd.append("--full")
-        proc = subprocess.run(cmd, env=env)
-        if proc.returncode != 0:
-            print(f"(distdyn subprocess failed with code {proc.returncode})")
-            failed = True
-        print()
+        title = "sharded streaming vs cold sharded recompute"
+        if on_tpu:
+            from benchmarks import bench_distributed_dynamic
+            section("distdyn", f"{title} ({jax.device_count()} chips)",
+                    lambda: bench_distributed_dynamic.run(small=small,
+                                                          repeats=3))
+        else:
+            failed |= forced_host_devices(
+                "distdyn", "benchmarks.bench_distributed_dynamic", title)
     if want("fleet"):
-        print("== fleet: multi-tenant serving fleet vs sequential "
-              "per-tenant sharded serving (8 forced host devices, "
-              "subprocess) ==")
-        # Forces the device count before JAX initializes, like distdyn
-        # (it emits BENCH_fleet.json itself).
-        env = dict(os.environ)
-        env["PYTHONPATH"] = "src" + (
-            os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-        cmd = [sys.executable, "-m", "benchmarks.bench_fleet"]
-        if not small:
-            cmd.append("--full")
-        proc = subprocess.run(cmd, env=env)
-        if proc.returncode != 0:
-            print(f"(fleet subprocess failed with code {proc.returncode})")
-            failed = True
-        print()
+        title = ("multi-tenant serving fleet vs sequential per-tenant "
+                 "sharded serving")
+        if on_tpu:
+            from benchmarks import bench_fleet
+            # best-of-5, as the subprocess entry point runs it.
+            section("fleet", f"{title} ({jax.device_count()} chips)",
+                    lambda: bench_fleet.run(small=small, repeats=5))
+        else:
+            failed |= forced_host_devices("fleet", "benchmarks.bench_fleet",
+                                          title)
     if want("roofline"):
         # Reads the committed BENCH_*.json artifacts (including any the
         # sections above just refreshed) — raises instead of emitting an

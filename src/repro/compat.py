@@ -1,17 +1,7 @@
-"""Version-portability shims for JAX API skew.
+"""Mesh construction shared by every sharded path.
 
-Two skews currently bite:
-
-  * mesh construction — newer JAX exposes ``jax.sharding.AxisType`` and
-    ``jax.make_mesh(..., axis_types=...)``; older releases (e.g. 0.4.x) have
-    ``jax.make_mesh`` without ``axis_types``, and the oldest only have
-    ``jax.sharding.Mesh``.  Every mesh in this repo is built with Auto axis
-    semantics, so the portable spelling is just ``make_mesh`` below.
-  * ``jax.lax.axis_size`` — absent on 0.4.x; ``axis_size`` below falls back
-    to ``psum(1, axis)`` (a constant inside shard_map bodies).
-
-Keep ALL version probing in this module — call sites must not touch
-``jax.sharding.AxisType`` / ``jax.lax.axis_size`` directly.
+Every mesh in this repo is built with Auto axis semantics; ``make_mesh``
+spells that once.
 """
 
 from __future__ import annotations
@@ -19,35 +9,10 @@ from __future__ import annotations
 import jax
 
 
-def _auto_axis_types(n_axes: int):
-    """(AxisType.Auto,) * n_axes on JAX versions that have it, else None."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return None
-    return (axis_type.Auto,) * n_axes
-
-
-def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with Auto axis types, across JAX versions."""
-    axis_shapes = tuple(axis_shapes)
+def make_mesh(axis_shapes, axis_names, devices=None):
+    """``jax.make_mesh`` with Auto axis types (over ``devices`` if given,
+    else all devices)."""
     axis_names = tuple(axis_names)
-    axis_types = _auto_axis_types(len(axis_names))
-    if hasattr(jax, "make_mesh"):
-        if axis_types is not None:
-            try:
-                return jax.make_mesh(axis_shapes, axis_names,
-                                     axis_types=axis_types)
-            except TypeError:  # make_mesh exists but predates axis_types
-                pass
-        return jax.make_mesh(axis_shapes, axis_names)
-    from jax.experimental import mesh_utils
-
-    devices = mesh_utils.create_device_mesh(axis_shapes)
-    return jax.sharding.Mesh(devices, axis_names)
-
-
-def axis_size(axis_name):
-    """Size of a named mesh axis, usable inside shard_map/pmap bodies."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+    return jax.make_mesh(tuple(axis_shapes), axis_names,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names), devices=devices)

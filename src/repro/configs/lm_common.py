@@ -67,8 +67,6 @@ def make_sharded_ce(cfg: tf.TransformerConfig, mesh: Mesh):
     the `model` axis — the full (B, S, V) f32 logits are NEVER materialized
     or gathered (they peak at ~40 GB/chip on the train_4k cells otherwise).
     """
-    from jax.experimental.shard_map import shard_map
-
     dp = dp_axes(mesh)
     axes = tuple(mesh.axis_names)
     n_model = mesh.shape["model"]
@@ -105,9 +103,9 @@ def make_sharded_ce(cfg: tf.TransformerConfig, mesh: Mesh):
         head = (params["embed"].T if cfg.tie_embeddings
                 else params["lm_head"])
         head_spec = P(F, "model")   # embed.T of P('model', F) / lm_head
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(P(dp, None, None), head_spec, P(dp, None)),
-                       out_specs=P(), check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(P(dp, None, None), head_spec, P(dp, None)),
+                           out_specs=P(), check_vma=False)
         return fn(x, head, batch["labels"])
 
     return loss

@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 from repro.core.distributed import (ShardedGraphSpec, _best_moves_shard,
@@ -788,11 +787,11 @@ class LouvainArch:
             arg_specs[0]["comm_sizes"] = S((n_pad + 1,), I32)
             shardings[0]["comm_sizes"] = NamedSharding(mesh, rep)
             body = functools.partial(_move_round_delta, axes, spec, 4)
-            fn_s = shard_map(
+            fn_s = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(edge, edge, edge, rep, rep, rep, rep, rep),
                 out_specs=(rep, rep, rep, edge, rep, rep),
-                check_rep=False)
+                check_vma=False)
 
             def step(batch):
                 return fn_s(batch["src"], batch["dst"], batch["w"],
@@ -806,10 +805,10 @@ class LouvainArch:
                 return _round_body(axes, spec, src_l, dst_l, w_l, comm,
                                    sigma, k, frontier, jnp.int32(0), 2, m)
 
-            fn_s = shard_map(round_shard, mesh=mesh,
-                             in_specs=(edge, edge, edge, rep, rep, rep, rep),
-                             out_specs=(rep, rep, edge, rep),
-                             check_rep=False)
+            fn_s = jax.shard_map(
+                round_shard, mesh=mesh,
+                in_specs=(edge, edge, edge, rep, rep, rep, rep),
+                out_specs=(rep, rep, edge, rep), check_vma=False)
         else:
             if "a2a" in variant:
                 body = functools.partial(_aggregate_a2a_body, axes, spec, 4)
@@ -817,10 +816,10 @@ class LouvainArch:
                 body = functools.partial(_aggregate_gather_body, axes, spec)
             outs = (edge, edge, edge, rep, rep)
 
-            fn_s = shard_map(body, mesh=mesh,
-                             in_specs=(edge, edge, edge, rep),
-                             out_specs=outs,
-                             check_rep=False)
+            fn_s = jax.shard_map(body, mesh=mesh,
+                                 in_specs=(edge, edge, edge, rep),
+                                 out_specs=outs,
+                                 check_vma=False)
 
         if phase == "move":
             def step(batch):

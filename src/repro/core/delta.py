@@ -91,7 +91,7 @@ def sort_reduce_apply_slots(all_src, all_dst, all_w, rank, is_batch,
 
     ``backend`` selects the post-sort group-resolve: ``"xla"`` (segment_*
     reductions, the reference) or ``"pallas"`` (the fused carry-chained scan
-    kernel in ``repro.kernels.batch_apply`` — interpret mode off-TPU).  Both
+    kernel in ``repro.kernels.batch_apply`` — interpreted on the CPU).  Both
     produce bit-identical graphs and touched sets; only the internal
     ``chg_*`` encoding differs (all group slots vs one record per group),
     which scatters to the same mask.

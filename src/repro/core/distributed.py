@@ -37,9 +37,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
-
-from repro import compat
 
 from repro.core.comm import (CommPlan, boundary_mask, comm_plan,
                              compact_movers, label_bits, pack_bits,
@@ -146,7 +143,7 @@ def partition_graph_host(
 def _shard_index(axes):
     shard_ix = jax.lax.axis_index(axes[0])
     for ax in axes[1:]:
-        shard_ix = shard_ix * compat.axis_size(ax) + jax.lax.axis_index(ax)
+        shard_ix = shard_ix * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
     return shard_ix
 
 
@@ -776,12 +773,12 @@ def make_distributed_move(
             return (comm_out, st.sigma, st.iters, st.dq_sum,
                     st.iters * jnp.int32(gate_fraction), st.comm_fb)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             body_shard, mesh=mesh,
             in_specs=(edge_spec, edge_spec, edge_spec, rep, rep, rep, rep,
                       rep, rep),
             out_specs=(rep, rep, rep, rep, rep, rep),
-            check_rep=False,
+            check_vma=False,
         )
         return fn(src_g, dst_g, w_g, comm, sigma, k, frontier_g, m, tolerance)
 
@@ -851,12 +848,12 @@ def make_distributed_refine(
             return (comm_out, st.iters, st.dq_sum,
                     st.iters * jnp.int32(gate_fraction), st.comm_fb)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             body_shard, mesh=mesh,
             in_specs=(edge_spec, edge_spec, edge_spec, rep, rep, rep, rep,
                       rep),
             out_specs=(rep, rep, rep, rep, rep),
-            check_rep=False,
+            check_vma=False,
         )
         return fn(src_g, dst_g, w_g, outer, k, n_live, m, tolerance)
 
@@ -959,9 +956,10 @@ def make_distributed_aggregate(mesh: Mesh, axes: Tuple[str, ...],
         owned_max = jax.lax.pmax(jnp.sum(jnp.where(live2, 1, 0)), axes)
         return o_ci, o_cj, o_w, e_valid, owned_max
 
-    fn = shard_map(body, mesh=mesh, in_specs=(edge_spec, edge_spec, edge_spec, rep),
-                   out_specs=(edge_spec, edge_spec, edge_spec, rep, rep),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(edge_spec, edge_spec, edge_spec, rep),
+                       out_specs=(edge_spec, edge_spec, edge_spec, rep, rep),
+                       check_vma=False)
     return jax.jit(fn)
 
 

@@ -49,7 +49,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.delta import EdgeBatch, sort_reduce_apply_slots
 from repro.core.distributed import (ShardedGraphSpec,
@@ -177,11 +176,11 @@ def make_sharded_batch_apply(mesh: Mesh, axes: Tuple[str, ...],
         if traced_n_limit:
             operands = operands + (n_limit_op,)
             in_specs = in_specs + (rep,)
-        fn = shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=in_specs,
             out_specs=(edge_spec, edge_spec, edge_spec, rep, rep, rep),
-            check_rep=False,
+            check_vma=False,
         )
         return fn(*operands)
 

@@ -21,6 +21,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import kernels
 from repro.core.engine import (ConstrainedScanner, EngineConfig, MoveEngine,
                                MoveState, gated_move_mask,
                                mask_cross_outer_slots, round_gate,
@@ -211,7 +212,7 @@ def move_phase_ell(
     are masked on device, so the host-side bucketing is reused as-is.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = kernels.interpret_mode()
     blocks, leftover_np = to_ell_blocks(graph, widths)
     leftover = jnp.asarray(leftover_np)
 

@@ -317,8 +317,6 @@ def build_halo_step(arch_id: str, shape_name: str, mesh: Mesh, *,
                     needs_positions: bool = False):
     """(train_step, arg_specs, in_shardings) for the halo-distributed
     full-graph variant of gin-tu / equiformer-v2."""
-    from jax.experimental.shard_map import shard_map
-
     from repro.configs.gnn_common import GNN_SHAPES, pad512
     from repro.optim import AdamWConfig, adamw_update
     from repro.optim.adamw import AdamWState
@@ -375,8 +373,8 @@ def build_halo_step(arch_id: str, shape_name: str, mesh: Mesh, *,
         batch_order = ("node_feat", "positions", "edge_src", "edge_dst",
                        "labels", "send_idx")
 
-    loss_sharded = shard_map(shard_loss, mesh=mesh, in_specs=in_specs,
-                             out_specs=rep, check_rep=False)
+    loss_sharded = jax.shard_map(shard_loss, mesh=mesh, in_specs=in_specs,
+                                 out_specs=rep, check_vma=False)
 
     def train_step(params, opt_state, batch):
         args = tuple(batch[k] for k in batch_order)

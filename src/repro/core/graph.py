@@ -102,13 +102,15 @@ def build_csr(
         first = np.ones(len(key), dtype=bool)
         first[1:] = key[1:] != key[:-1]
         gid = np.cumsum(first) - 1
-        wsum = np.zeros(gid[-1] + 1, dtype=np.float64)
-        np.add.at(wsum, gid, weight)
+        # bincount sums each group in slot order in float64, as a
+        # sequential np.add.at would, in one vectorised pass.
+        wsum = np.bincount(gid, weights=weight)
+        # The dedup key is the CSR order, so the groups are already in it.
         src, dst, weight = src[first], dst[first], wsum.astype(np.float32)
-
-    # CSR order.
-    order = np.argsort(src.astype(np.int64) * (n + 1) + dst, kind="stable")
-    src, dst, weight = src[order], dst[order], weight[order]
+    else:
+        order = np.argsort(src.astype(np.int64) * (n + 1) + dst,
+                           kind="stable")
+        src, dst, weight = src[order], dst[order], weight[order]
 
     e = len(src)
     n_cap = int(n_cap if n_cap is not None else n)
@@ -116,7 +118,7 @@ def build_csr(
     assert n_cap >= n and e_cap >= e, "capacity below graph size"
 
     counts = np.zeros(n_cap + 1, dtype=np.int64)
-    np.add.at(counts[1:], src, 1)
+    counts[1:] = np.bincount(src, minlength=n_cap)
     indptr = np.cumsum(counts).astype(np.int32)
 
     pad_i = np.full(e_cap - e, n_cap, dtype=np.int32)
@@ -255,7 +257,7 @@ class ELLBlock(NamedTuple):
 
 def to_ell_blocks(
     graph: CSRGraph,
-    widths: Tuple[int, ...] = (16, 64, 256, 1024),
+    widths: Tuple[int, ...] = (16, 64, 256),
     *,
     row_align: int = 8,
 ) -> Tuple[list, np.ndarray]:
@@ -285,10 +287,14 @@ def to_ell_blocks(
         cols = np.full((n_rows, width), n_cap, dtype=np.int32)
         wmat = np.zeros((n_rows, width), dtype=np.float32)
         rows[: len(sel)] = sel
-        for r, v in enumerate(sel):
-            s, e = indptr[v], indptr[v + 1]
-            cols[r, : e - s] = indices[s:e]
-            wmat[r, : e - s] = weights[s:e]
+        # Row r takes slots indptr[v] .. indptr[v] + deg[v] of v = sel[r],
+        # scattered in one pass instead of a loop over rows.
+        d = deg[sel]
+        row_ix = np.repeat(np.arange(len(sel)), d)
+        col_ix = np.arange(int(d.sum())) - np.repeat(np.cumsum(d) - d, d)
+        slot = indptr[sel].repeat(d) + col_ix
+        cols[row_ix, col_ix] = indices[slot]
+        wmat[row_ix, col_ix] = weights[slot]
         assigned[sel] = True
         blocks.append(ELLBlock(jnp.asarray(rows), jnp.asarray(cols), jnp.asarray(wmat)))
     leftover = np.where(~assigned)[0].astype(np.int32)

@@ -36,19 +36,12 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # scratch memory-space types live in the TPU namespace
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover - CPU-only wheels
-    pltpu = None
+from repro import kernels
+from repro.kernels import inclusive_sum, shift_right
 
 _BLOCK = 512  # lanes per program (multiple of 128)
-
-
-def _shift_right(x: jax.Array, d: int, fill) -> jax.Array:
-    """(1, T) lane shift by ``d`` with constant fill on the left."""
-    return jnp.concatenate(
-        [jnp.full((1, d), fill, x.dtype), x[:, :-d]], axis=1)
 
 
 def _coarsen_kernel(sent: int, ci_ref, cj_ref, w_ref,
@@ -72,30 +65,31 @@ def _coarsen_kernel(sent: int, ci_ref, cj_ref, w_ref,
 
     # Lane 0's "previous slot" is the carry from the preceding tile.
     lane0 = jax.lax.broadcasted_iota(jnp.int32, ci.shape, 1) == 0
-    prev_ci = jnp.where(lane0, ckey_ref[0], _shift_right(ci, 1, 0))
-    prev_cj = jnp.where(lane0, ckey_ref[1], _shift_right(cj, 1, 0))
+    prev_ci = jnp.where(lane0, ckey_ref[0], shift_right(ci, 1, 0))
+    prev_cj = jnp.where(lane0, ckey_ref[1], shift_right(cj, 1, 0))
     is_first = (ci != prev_ci) | (cj != prev_cj)
 
     # Segmented inclusive sum-scan (Hillis-Steele): per slot, the weight sum
     # of its group FROM the group's first in-tile slot; slots whose group
     # opened in an earlier tile (no boundary anywhere left of them) add the
-    # carried open-group partial sum.
-    s, f = w, is_first
+    # carried open-group partial sum.  The boundary flag rides in int32
+    # lanes (0/1) so every shift is a 32-bit one.
+    s, f = w, is_first.astype(jnp.int32)
     d = 1
     while d < ci.shape[1]:
-        ps = _shift_right(s, d, 0.0)
-        pf = _shift_right(f, d, False)
-        s = jnp.where(f, s, s + ps)
+        ps = shift_right(s, d, 0.0)
+        pf = shift_right(f, d, 0)
+        s = jnp.where(f > 0, s, s + ps)
         f = f | pf
         d *= 2
-    open_sum = jnp.where(f, s, s + copen_ref[0])
+    open_sum = jnp.where(f > 0, s, s + copen_ref[0])
 
     # Group finalized at slot i = the group open at slot i - 1.
-    prev_open = jnp.where(lane0, copen_ref[0], _shift_right(open_sum, 1, 0.0))
+    prev_open = jnp.where(lane0, copen_ref[0], shift_right(open_sum, 1, 0.0))
     emit = is_first & (prev_ci != sent) & (prev_ci >= 0)
 
     em = emit.astype(jnp.int32)
-    incl = jnp.cumsum(em, axis=1)
+    incl = inclusive_sum(em)
     emit_ref[...] = em
     pos_ref[...] = ccnt_ref[0] + incl - em
     gsrc_ref[...] = prev_ci
@@ -128,35 +122,33 @@ def coarsen_groups_pallas(
     sort before sentinel padding); ``g_w`` its accumulated weight.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = kernels.interpret_mode()
     total = s_ci.shape[0]
     tiles = total // block + 1             # >= 1 trailing pad slot, always
     padded = tiles * block
 
+    # One (1, padded) row: a (1, block) block spans the whole first dim,
+    # which the TPU's (8, 128) block rule accepts; a (tiles, block) layout
+    # with (1, block) blocks it refuses.
     def pad(x, fill, dtype):
         return jnp.concatenate(
             [x.astype(dtype), jnp.full((padded - total,), fill, dtype)]
-        ).reshape(tiles, block)
+        ).reshape(1, padded)
 
     ins = (pad(s_ci, sent, jnp.int32), pad(s_cj, sent, jnp.int32),
            pad(s_w, 0.0, jnp.float32))
 
-    row = pl.BlockSpec((1, block), lambda i: (i, 0))
+    row = pl.BlockSpec((1, block), lambda i: (0, i))
     out_shape = (
-        jax.ShapeDtypeStruct((tiles, block), jnp.int32),    # emit
-        jax.ShapeDtypeStruct((tiles, block), jnp.int32),    # pos
-        jax.ShapeDtypeStruct((tiles, block), jnp.int32),    # group src
-        jax.ShapeDtypeStruct((tiles, block), jnp.int32),    # group dst
-        jax.ShapeDtypeStruct((tiles, block), jnp.float32),  # group weight
+        jax.ShapeDtypeStruct((1, padded), jnp.int32),    # emit
+        jax.ShapeDtypeStruct((1, padded), jnp.int32),    # pos
+        jax.ShapeDtypeStruct((1, padded), jnp.int32),    # group src
+        jax.ShapeDtypeStruct((1, padded), jnp.int32),    # group dst
+        jax.ShapeDtypeStruct((1, padded), jnp.float32),  # group weight
     )
-    if pltpu is not None:
-        scratch = [pltpu.SMEM((2,), jnp.int32),     # prev slot key (ci, cj)
-                   pltpu.SMEM((1,), jnp.float32),   # open-group partial sum
-                   pltpu.SMEM((1,), jnp.int32)]     # emitted-group count
-    else:  # pragma: no cover - interpret-only environments
-        scratch = [jax.ShapeDtypeStruct((2,), jnp.int32),
-                   jax.ShapeDtypeStruct((1,), jnp.float32),
-                   jax.ShapeDtypeStruct((1,), jnp.int32)]
+    scratch = [pltpu.SMEM((2,), jnp.int32),     # prev slot key (ci, cj)
+               pltpu.SMEM((1,), jnp.float32),   # open-group partial sum
+               pltpu.SMEM((1,), jnp.int32)]     # emitted-group count
 
     outs = pl.pallas_call(
         functools.partial(_coarsen_kernel, sent),

@@ -34,19 +34,12 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # scratch memory-space types live in the TPU namespace
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover - CPU-only wheels
-    pltpu = None
+from repro import kernels
+from repro.kernels import inclusive_sum, shift_right
 
 _BLOCK = 512  # lanes per program (multiple of 128)
-
-
-def _shift_right(x: jax.Array, d: int, fill) -> jax.Array:
-    """(1, T) lane shift by ``d`` with constant fill on the left."""
-    return jnp.concatenate(
-        [jnp.full((1, d), fill, x.dtype), x[:, :-d]], axis=1)
 
 
 def _resolve_kernel(sent: int, src_ref, dst_ref, w_ref, batch_ref,
@@ -75,33 +68,34 @@ def _resolve_kernel(sent: int, src_ref, dst_ref, w_ref, batch_ref,
 
     # Lane 0's "previous slot" is the carry from the preceding tile.
     lane0 = jax.lax.broadcasted_iota(jnp.int32, src.shape, 1) == 0
-    prev_src = jnp.where(lane0, ckey_ref[0], _shift_right(src, 1, 0))
-    prev_dst = jnp.where(lane0, ckey_ref[1], _shift_right(dst, 1, 0))
-    prev_w = jnp.where(lane0, clastw_ref[0], _shift_right(w, 1, 0.0))
-    prev_b = jnp.where(lane0, clastb_ref[0], _shift_right(b, 1, 0))
+    prev_src = jnp.where(lane0, ckey_ref[0], shift_right(src, 1, 0))
+    prev_dst = jnp.where(lane0, ckey_ref[1], shift_right(dst, 1, 0))
+    prev_w = jnp.where(lane0, clastw_ref[0], shift_right(w, 1, 0.0))
+    prev_b = jnp.where(lane0, clastb_ref[0], shift_right(b, 1, 0))
     is_first = (src != prev_src) | (dst != prev_dst)
 
     # Segmented copy-scan (Hillis-Steele): per slot, the (w, batch) of the
     # first slot of the group CONTAINING it; unanchored slots (group opened
-    # in an earlier tile) fall back to the carried open-group state.
-    fw, fb, anch = w, b, is_first
+    # in an earlier tile) fall back to the carried open-group state.  The
+    # anchor flag rides in int32 lanes (0/1) so every shift is 32-bit.
+    fw, fb, anch = w, b, is_first.astype(jnp.int32)
     d = 1
     while d < src.shape[1]:
-        pfw = _shift_right(fw, d, 0.0)
-        pfb = _shift_right(fb, d, 0)
-        panch = _shift_right(anch, d, False)
-        fw = jnp.where(anch, fw, pfw)
-        fb = jnp.where(anch, fb, pfb)
+        pfw = shift_right(fw, d, 0.0)
+        pfb = shift_right(fb, d, 0)
+        panch = shift_right(anch, d, 0)
+        fw = jnp.where(anch > 0, fw, pfw)
+        fb = jnp.where(anch > 0, fb, pfb)
         anch = anch | panch
         d *= 2
-    open_fw = jnp.where(anch, fw, copenw_ref[0])
-    open_fb = jnp.where(anch, fb, copenb_ref[0])
+    open_fw = jnp.where(anch > 0, fw, copenw_ref[0])
+    open_fb = jnp.where(anch > 0, fb, copenb_ref[0])
 
     # Group finalized at slot i = the group open at slot i - 1.
     prev_open_fw = jnp.where(lane0, copenw_ref[0],
-                             _shift_right(open_fw, 1, 0.0))
+                             shift_right(open_fw, 1, 0.0))
     prev_open_fb = jnp.where(lane0, copenb_ref[0],
-                             _shift_right(open_fb, 1, 0))
+                             shift_right(open_fb, 1, 0))
 
     new_w = prev_w                                   # last slot wins
     old_w = jnp.where(prev_open_fb == 1, 0.0, prev_open_fw)
@@ -112,7 +106,7 @@ def _resolve_kernel(sent: int, src_ref, dst_ref, w_ref, batch_ref,
     changed = is_first & live & (prev_b == 1) & (old_w != new_w)
 
     kp = keep.astype(jnp.int32)
-    incl = jnp.cumsum(kp, axis=1)
+    incl = inclusive_sum(kp)
     keep_ref[...] = kp
     pos_ref[...] = ckept_ref[0] + incl - kp
     fsrc_ref[...] = prev_src
@@ -150,42 +144,36 @@ def resolve_groups_pallas(
     resolved weight differs from its pre-batch weight.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = kernels.interpret_mode()
     total = s_src.shape[0]
     tiles = total // block + 1             # >= 1 trailing pad slot, always
     padded = tiles * block
 
+    # One (1, padded) row, as in ``repro.kernels.aggregate``: (1, block)
+    # blocks then span the whole first dim, which the TPU accepts.
     def pad(x, fill, dtype):
         return jnp.concatenate(
             [x.astype(dtype), jnp.full((padded - total,), fill, dtype)]
-        ).reshape(tiles, block)
+        ).reshape(1, padded)
 
     ins = (pad(s_src, sent, jnp.int32), pad(s_dst, sent, jnp.int32),
            pad(s_w, 0.0, jnp.float32), pad(s_batch, 0, jnp.int32))
 
-    row = pl.BlockSpec((1, block), lambda i: (i, 0))
+    row = pl.BlockSpec((1, block), lambda i: (0, i))
     out_shape = (
-        jax.ShapeDtypeStruct((tiles, block), jnp.int32),    # keep
-        jax.ShapeDtypeStruct((tiles, block), jnp.int32),    # pos
-        jax.ShapeDtypeStruct((tiles, block), jnp.int32),    # src
-        jax.ShapeDtypeStruct((tiles, block), jnp.int32),    # dst
-        jax.ShapeDtypeStruct((tiles, block), jnp.float32),  # w
-        jax.ShapeDtypeStruct((tiles, block), jnp.int32),    # changed
+        jax.ShapeDtypeStruct((1, padded), jnp.int32),    # keep
+        jax.ShapeDtypeStruct((1, padded), jnp.int32),    # pos
+        jax.ShapeDtypeStruct((1, padded), jnp.int32),    # src
+        jax.ShapeDtypeStruct((1, padded), jnp.int32),    # dst
+        jax.ShapeDtypeStruct((1, padded), jnp.float32),  # w
+        jax.ShapeDtypeStruct((1, padded), jnp.int32),    # changed
     )
-    if pltpu is not None:
-        scratch = [pltpu.SMEM((2,), jnp.int32),     # prev slot key
-                   pltpu.SMEM((1,), jnp.float32),   # prev slot w
-                   pltpu.SMEM((1,), jnp.int32),     # prev slot batch
-                   pltpu.SMEM((1,), jnp.float32),   # open-group first w
-                   pltpu.SMEM((1,), jnp.int32),     # open-group first batch
-                   pltpu.SMEM((1,), jnp.int32)]     # kept-count prefix
-    else:  # pragma: no cover - interpret-only environments
-        scratch = [jax.ShapeDtypeStruct((2,), jnp.int32),
-                   jax.ShapeDtypeStruct((1,), jnp.float32),
-                   jax.ShapeDtypeStruct((1,), jnp.int32),
-                   jax.ShapeDtypeStruct((1,), jnp.float32),
-                   jax.ShapeDtypeStruct((1,), jnp.int32),
-                   jax.ShapeDtypeStruct((1,), jnp.int32)]
+    scratch = [pltpu.SMEM((2,), jnp.int32),     # prev slot key
+               pltpu.SMEM((1,), jnp.float32),   # prev slot w
+               pltpu.SMEM((1,), jnp.int32),     # prev slot batch
+               pltpu.SMEM((1,), jnp.float32),   # open-group first w
+               pltpu.SMEM((1,), jnp.int32),     # open-group first batch
+               pltpu.SMEM((1,), jnp.int32)]     # kept-count prefix
 
     outs = pl.pallas_call(
         functools.partial(_resolve_kernel, sent),

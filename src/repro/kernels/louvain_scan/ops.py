@@ -1,7 +1,8 @@
 """Jit'd public wrapper for the Louvain ELL scan kernel.
 
-`louvain_scan` dispatches to the Pallas kernel (TPU target; interpret=True on
-CPU) or the pure-jnp reference, choosing VMEM-safe block shapes per ELL width.
+`louvain_scan` dispatches to the Pallas kernel (compiled on a TPU, interpreted
+on the CPU — ``repro.kernels.interpret_mode``) or the pure-jnp reference,
+choosing VMEM-safe block shapes per ELL width.
 `prepare_ell_inputs` builds the pre-gathered per-slot arrays from graph state
 (the gathers are XLA's job — Pallas TPU kernels keep to dense tiles).
 """
@@ -13,23 +14,42 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import kernels
 from repro.core.graph import ELLBlock
 from repro.kernels.louvain_scan.fused import (louvain_fused_pallas,
                                               louvain_fused_ref)
 from repro.kernels.louvain_scan.louvain_scan import louvain_scan_pallas
 from repro.kernels.louvain_scan.ref import louvain_scan_ref
 
-# width -> rows per program, keeping the (B, D, D) compare tile + operands
-# comfortably inside ~4 MB of VMEM (paper-analogue of Far-KV sizing).
-_BLOCK_ROWS = {16: 256, 64: 64, 256: 8, 1024: 1}
+# width -> most rows per program, keeping the (B, D, D) compare tile +
+# operands comfortably inside ~4 MB of VMEM (paper-analogue of Far-KV
+# sizing).  Every entry is a multiple of 8, the TPU's sublane tile; 256 is
+# the widest tile, since even 8 rows of a wider one overflow VMEM.
+_BLOCK_ROWS = {16: 256, 64: 64, 256: 8}
 
 
 def block_rows_for_width(width: int) -> int:
-    best = 8
     for w_key, rows in _BLOCK_ROWS.items():
         if width <= w_key:
             return rows
-    return 1
+    raise ValueError(f"ELL width {width} exceeds the widest kernel tile "
+                     f"({max(_BLOCK_ROWS)})")
+
+
+def tile_rows(r: int, d: int) -> int:
+    """Rows per program for an (R, D) ELL block.
+
+    The TPU takes a (rows, D) block only when rows is a multiple of 8 or
+    all of R.  ``to_ell_blocks`` pads R to a multiple of 8, so the answer is
+    the largest multiple of 8 that divides R within the width's cap; an R
+    that is not a multiple of 8 takes one whole-array block.
+    """
+    if r % 8:
+        return r
+    rows = max(8, min(block_rows_for_width(d), r) // 8 * 8)
+    while r % rows:
+        rows -= 8
+    return rows
 
 
 def prepare_ell_inputs(
@@ -105,12 +125,8 @@ def louvain_fused(
             size_own, rows, front, m, round_ix,
             gate_fraction=gate_fraction, sentinel=sentinel)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    r, d = c_nbr.shape
-    rows_per = block_rows or block_rows_for_width(d)
-    rows_per = max(1, min(rows_per, r))
-    while r % rows_per:  # shrink to a divisor of R (rows are align-padded)
-        rows_per -= 1
+        interpret = kernels.interpret_mode()
+    rows_per = block_rows or tile_rows(*c_nbr.shape)
     out_c, out_dq, out_mv = louvain_fused_pallas(
         c_nbr, w_nbr, sigma_nbr, size_nbr, k_i, c_own, sigma_own, size_own,
         rows, front, m, round_ix, gate_fraction=gate_fraction,
@@ -135,12 +151,8 @@ def louvain_scan(
     if not use_pallas:
         return louvain_scan_ref(c_nbr, w_nbr, sigma_nbr, k_i, c_own, sigma_own, m)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    r, d = c_nbr.shape
-    rows = block_rows or block_rows_for_width(d)
-    rows = max(1, min(rows, r))
-    while r % rows:  # shrink to a divisor of R (rows are align-padded anyway)
-        rows -= 1
+        interpret = kernels.interpret_mode()
+    rows = block_rows or tile_rows(*c_nbr.shape)
     out_c, out_dq = louvain_scan_pallas(
         c_nbr, w_nbr, sigma_nbr, k_i, c_own, sigma_own, m,
         block_rows=rows, interpret=interpret,

@@ -31,10 +31,9 @@ def modularity_np(src, dst, w, membership) -> float:
     if m <= 0:
         return 0.0
     internal = w[membership[src] == membership[dst]].sum()
-    k = np.zeros(len(membership), np.float64)
-    np.add.at(k, src, w)
-    sigma = np.zeros(int(membership.max()) + 1, np.float64)
-    np.add.at(sigma, membership, k)
+    # bincount sums each bin in index order in float64, as np.add.at does.
+    k = np.bincount(src, weights=w, minlength=len(membership))
+    sigma = np.bincount(membership, weights=k)
     return float(internal / (2 * m) - np.sum((sigma / (2 * m)) ** 2))
 
 

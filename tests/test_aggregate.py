@@ -63,7 +63,7 @@ def _coarse_dict(g):
     return {(int(src[i]), int(dst[i])): float(w[i]) for i in range(e)}
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(st.integers(min_value=0, max_value=10_000),
        st.integers(min_value=2, max_value=8))
 def test_aggregate_matches_numpy_oracle(seed, n_groups):
@@ -92,7 +92,7 @@ def test_aggregate_matches_numpy_oracle(seed, n_groups):
         assert any(a == b for a, b in got)
 
 
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)
 @given(st.integers(min_value=0, max_value=10_000))
 def test_aggregate_padding_and_conservation(seed):
     """Beyond e_valid every slot is sentinel/0; sum(w') == sum(w) exactly;
@@ -180,7 +180,7 @@ def test_resolve_coarse_capacity_policy():
         assert n_new & (n_new - 1) == 0 and e_new & (e_new - 1) == 0
 
 
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)
 @given(st.integers(min_value=0, max_value=10_000))
 def test_ladder_rebucket_round_trip(seed):
     """Re-bucket a coarse graph down to its tier and back up: every buffer

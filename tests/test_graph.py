@@ -98,3 +98,63 @@ def test_ell_blocks_degree_bounds():
         live = rows[rows < g.n_cap]
         assert np.all(deg[live] <= width)
     assert all(deg[v] > widths[-1] for v in leftover)
+
+
+def _ell_blocks_loop(graph, widths, row_align=8):
+    """Row-by-row reference of ``to_ell_blocks`` (the pre-vectorised loop)."""
+    indptr = np.asarray(graph.indptr)
+    indices = np.asarray(graph.indices)
+    weights = np.asarray(graph.weights)
+    n, n_cap = int(graph.n_valid), graph.n_cap
+    deg = indptr[1:n + 1] - indptr[:n]
+    out, lo = [], 0
+    assigned = np.zeros(n, bool)
+    for width in widths:
+        sel = np.where((deg > lo) & (deg <= width))[0]
+        if width == widths[0]:
+            sel = np.where(deg <= width)[0]
+        lo = width
+        n_rows = int(np.ceil(max(len(sel), 1) / row_align) * row_align)
+        rows = np.full(n_rows, n_cap, np.int32)
+        cols = np.full((n_rows, width), n_cap, np.int32)
+        wmat = np.zeros((n_rows, width), np.float32)
+        rows[:len(sel)] = sel
+        for r, v in enumerate(sel):
+            s, e = indptr[v], indptr[v + 1]
+            cols[r, :e - s] = indices[s:e]
+            wmat[r, :e - s] = weights[s:e]
+        assigned[sel] = True
+        out.append((rows, cols, wmat))
+    return out, np.where(~assigned)[0]
+
+
+@pytest.mark.parametrize("seed,widths", [(0, (16, 64, 256)), (1, (4, 8)),
+                                         (2, (2, 16, 32))])
+def test_ell_blocks_match_row_loop(seed, widths):
+    from repro.data import rmat_graph
+    g = rmat_graph(9, edge_factor=8, seed=seed)
+    blocks, leftover = to_ell_blocks(g, widths)
+    want, want_left = _ell_blocks_loop(g, widths)
+    np.testing.assert_array_equal(leftover, want_left)
+    for b, ref in zip(blocks, want):
+        for got, exp in zip(b, ref):
+            np.testing.assert_array_equal(np.asarray(got), exp)
+
+
+def test_dedup_sums_match_sequential_add():
+    """Parallel-slot sums equal a sequential float64 np.add.at."""
+    rng = np.random.default_rng(5)
+    src = rng.integers(0, 30, 4000)
+    dst = rng.integers(0, 30, 4000)
+    w = rng.random(4000).astype(np.float32)
+    g = build_csr(src, dst, w, 30)
+    key = src * 31 + dst
+    order = np.argsort(key, kind="stable")
+    first = np.ones(len(key), bool)
+    first[1:] = key[order][1:] != key[order][:-1]
+    gid = np.cumsum(first) - 1
+    want = np.zeros(gid[-1] + 1)
+    np.add.at(want, gid, w[order].astype(np.float64))
+    e = int(g.e_valid)
+    np.testing.assert_array_equal(np.asarray(g.weights)[:e],
+                                  want.astype(np.float32))

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 import networkx as nx
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.gnn_halo import (HaloSpec, build_halo_inputs,
                                  equiformer_halo_loss_shard,
@@ -66,8 +65,8 @@ axes = ("i",)
 shard1, rep = P("i"), P()
 
 def run_halo(loss_shard, params, arrays, in_specs):
-    fn = shard_map(loss_shard, mesh=mesh, in_specs=in_specs, out_specs=rep,
-                   check_rep=False)
+    fn = jax.shard_map(loss_shard, mesh=mesh, in_specs=in_specs, out_specs=rep,
+                       check_vma=False)
     with mesh:
         return float(jax.jit(fn)(params, *arrays))
 

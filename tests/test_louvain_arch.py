@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.configs.louvain_arch import (_aggregate_a2a_body,
                                         _aggregate_gather_body)
@@ -47,8 +46,8 @@ axes = ("i",)
 edge, rep = P("i"), P()
 
 def run(body):
-    fn = shard_map(body, mesh=mesh, in_specs=(edge, edge, edge, rep),
-                   out_specs=(edge, edge, edge, rep, rep), check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(edge, edge, edge, rep),
+                       out_specs=(edge, edge, edge, rep, rep), check_vma=False)
     with mesh:
         return jax.jit(fn)(jnp.asarray(src), jnp.asarray(dst),
                            jnp.asarray(w), comm)
@@ -79,8 +78,8 @@ max_vs_truth = max(abs(d_a2a[k] - truth[k]) for k in truth)
 comm_skew = jnp.asarray(np.concatenate(
     [rng.integers(0, 8, n).astype(np.int32), [n]]))
 def run_skew(body):
-    fn = shard_map(body, mesh=mesh, in_specs=(edge, edge, edge, rep),
-                   out_specs=(edge, edge, edge, rep, rep), check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(edge, edge, edge, rep),
+                       out_specs=(edge, edge, edge, rep, rep), check_vma=False)
     with mesh:
         return jax.jit(fn)(jnp.asarray(src), jnp.asarray(dst),
                            jnp.asarray(w), comm_skew)
@@ -103,13 +102,13 @@ def base_round(src_l, dst_l, w_l, comm_, sigma_, k_, m_):
     return _round_body(axes, spec, src_l, dst_l, w_l, comm_, sigma_, k_,
                        frontier, jnp.int32(0), 2, m_)
 
-fn_b = shard_map(base_round, mesh=mesh,
-                 in_specs=(edge, edge, edge, rep, rep, rep, rep),
-                 out_specs=(rep, rep, edge, rep), check_rep=False)
-fn_d = shard_map(functools.partial(_move_round_delta, axes, spec, 1),
-                 mesh=mesh,
-                 in_specs=(edge, edge, edge, rep, rep, rep, rep, rep),
-                 out_specs=(rep, rep, rep, edge, rep, rep), check_rep=False)
+fn_b = jax.shard_map(base_round, mesh=mesh,
+                     in_specs=(edge, edge, edge, rep, rep, rep, rep),
+                     out_specs=(rep, rep, edge, rep), check_vma=False)
+fn_d = jax.shard_map(functools.partial(_move_round_delta, axes, spec, 1),
+                     mesh=mesh,
+                     in_specs=(edge, edge, edge, rep, rep, rep, rep, rep),
+                     out_specs=(rep, rep, rep, edge, rep, rep), check_vma=False)
 with mesh:
     cb, sb, fb, dqb = jax.jit(fn_b)(jnp.asarray(src), jnp.asarray(dst),
                                     jnp.asarray(w), comm0, sigma0, k_j,
