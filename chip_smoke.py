@@ -15,7 +15,9 @@ One chip (the default) runs three phases in one process:
                factor 16, 2^scale vertices) through ``louvain`` with the
                default config (Pallas aggregation on a TPU); the first
                pass's aggregation is then run with both backends, whose
-               coarse graphs must be identical;
+               coarse graphs must be identical; the first pass's move phase
+               is run again with the fused ELL kernel, whose level-0 Q must
+               be within 1e-3 of the default run's;
   c. stream    insert/delete batches of about 1e-4 |E| each through
                ``louvain_dynamic`` with the Pallas batch apply; the batches
                are then applied with both apply backends, whose graphs and
@@ -23,9 +25,11 @@ One chip (the default) runs three phases in one process:
 
 A backend pair differs in one jitted phase only, so b and c compare that
 phase's output on the real inputs: identical outputs give identical
-memberships, since all else is the same deterministic program.  A second
-scale-21 pass loop per backend would cost minutes of chip time each and
-push the run past the 1200 s a smoke run may take.
+memberships, since all else is the same deterministic program.  At scale
+21 the default run aggregates once (its second pass converges), so the
+first pass's aggregation is the only one.  A second scale-21 pass loop per
+backend would cost minutes of chip time each and push the run past the
+1200 s a smoke run may take.
 
 ``--chips 4`` runs ``distributed_louvain`` over a 4-chip mesh with the
 "gather" and "delta" exchanges (memberships must be identical), a few
@@ -33,7 +37,8 @@ batches of ``louvain_dynamic_sharded``, and a one-chip ``louvain`` on the
 same graph for Q, in four worker threads of the one process so that one
 run compiles while another holds the chips.  On one chip the phases run
 one after another (concurrent scale-21 runs exhaust the chip's 16 GB);
-only the host's R-MAT set-up overlaps the goldens.
+the host's R-MAT set-up overlaps the goldens, and the check phases'
+programs compile while the default run holds the chip.
 
 Each phase prints one line with the device kind, its wall seconds and the
 seconds its thread spent in XLA compilation (smoke timings, not benchmark
@@ -222,8 +227,57 @@ def rmat_with_stream(scale: int, seed: int):
     return rebucket_graph(g, g.n_cap, int(g.e_valid) + slack), batches
 
 
-def phase_static_and_stream(setup) -> None:
+def precompile_checks(g, batches, cfg) -> None:
+    """Compile the programs the check phases add to the default run's.
+
+    A jitted function lowered and compiled for some arguments is the
+    program a later call with arguments of the same shapes runs, so the
+    checks then compile nothing.  The arguments mirror those that
+    ``_aggregate_phase``, ``apply_edge_batch`` and ``move_phase_ell`` get.
+    """
     import jax.numpy as jnp
+
+    from repro import kernels
+    from repro.core import ell_move
+    from repro.core.delta import _apply_edge_batch
+    from repro.core.graph import to_ell_blocks
+    from repro.core.louvain import _aggregate_phase
+
+    cap = g.n_cap + 1
+    _aggregate_phase.lower(g, jnp.zeros((cap,), jnp.int32), jnp.int32(0),
+                           backend="sort").compile()
+    _apply_edge_batch.lower(g, batches[0], backend="xla").compile()
+    blocks, leftover = to_ell_blocks(g, cfg.ell_widths)
+    run = ell_move._ell_runner(len(blocks), True, kernels.interpret_mode(),
+                               cfg.max_iterations, cfg.use_pruning,
+                               cfg.gate_fraction, True, False)
+    k = g.vertex_weights()
+    run.lower(g, tuple(blocks), jnp.asarray(leftover), k, g.total_weight(),
+              jnp.arange(cap, dtype=jnp.int32), k, jnp.arange(cap) < g.n_valid,
+              jnp.float32(cfg.initial_tolerance)).compile()
+
+
+def fused_ell_level0(g, cfg):
+    """The first pass's move phase with the fused ELL kernel, renumbered as
+    ``louvain`` renumbers it: (level-0 membership, rounds)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core import ell_move
+    from repro.core.louvain import _renumber_and_fold
+
+    comm, iters, _ = ell_move.move_phase_ell(
+        g, jnp.float32(cfg.initial_tolerance),
+        max_iterations=cfg.max_iterations, use_pruning=cfg.use_pruning,
+        gate_fraction=cfg.gate_fraction, widths=cfg.ell_widths, fused=True)
+    _, _, folded = _renumber_and_fold(comm, g.n_valid, jnp.int32(g.n_cap),
+                                      jnp.arange(g.n_cap, dtype=jnp.int32))
+    return np.asarray(folded[:int(g.n_valid)]), int(iters)
+
+
+def phase_static_and_stream(pool, setup) -> None:
+    import jax.numpy as jnp
+    import numpy as np
 
     from repro.configs.louvain_arch import resolve_agg_backend
     from repro.core.delta import apply_edge_batch
@@ -237,7 +291,9 @@ def phase_static_and_stream(setup) -> None:
 
     check(resolve_agg_backend("auto") == "pallas",
           "agg_backend='auto' did not resolve to the Pallas kernel")
-    base, dt, ct = timed(louvain, g, LouvainConfig())
+    cfg = LouvainConfig()
+    warm = pool.submit(timed, precompile_checks, g, batches, cfg)
+    base, dt, ct = timed(louvain, g, cfg)
     q = host_q(g, base.membership)
     q_dev = louvain_modularity(g, base)
     check(abs(q - q_dev) <= Q_TOL, f"device Q {q_dev} vs host Q {q}")
@@ -245,6 +301,8 @@ def phase_static_and_stream(setup) -> None:
            Q_device=f"{q_dev:.6f}", passes=base.n_passes,
            rounds="/".join(str(p.iterations) for p in base.passes),
            communities=base.n_communities)
+    _, dt, ct = warm.result()
+    report("b.precompile_checks", dt, ct)
 
     # The first pass's aggregation, as ``louvain`` ran it: the renumbered
     # first-level partition of the input graph.
@@ -259,6 +317,17 @@ def phase_static_and_stream(setup) -> None:
            communities=int(n_comms),
            coarse_slots=int(coarse["sort"].e_valid), bit_identical=True)
     del coarse
+
+    (level0, iters), dt, ct = timed(fused_ell_level0, g, cfg)
+    q_ell, q_base = host_q(g, level0), host_q(g, base.levels[0])
+    check(abs(q_ell - q_base) <= Q_TOL,
+          f"fused ELL level-0 Q {q_ell} vs default {q_base}")
+    report("b.static.fused_ell_level0", dt, ct, Q=f"{q_ell:.6f}",
+           Q_default=f"{q_base:.6f}", rounds=iters,
+           rounds_default=base.passes[0].iterations,
+           communities=len(np.unique(level0)),
+           bit_identical=bool(np.array_equal(level0, base.levels[0])))
+    del level0
 
     res, dt, ct = timed(louvain_dynamic, g, batches, prev=base.membership,
                         apply_backend="pallas")
@@ -377,7 +446,7 @@ def main() -> None:
         else:
             check_kernels_lower()
             phase_goldens()
-            phase_static_and_stream(setup)
+            phase_static_and_stream(pool, setup)
     print(f"smoke total wall seconds: {time.perf_counter() - t0:.2f}",
           flush=True)
     print(json.dumps({"ok": True, "device": {

@@ -258,15 +258,16 @@ class ELLBlock(NamedTuple):
 def to_ell_blocks(
     graph: CSRGraph,
     widths: Tuple[int, ...] = (16, 64, 256),
-    *,
-    row_align: int = 8,
 ) -> Tuple[list, np.ndarray]:
     """Host-side degree bucketing: vertices with degree <= widths[k] (and >
     widths[k-1]) go to block k.  Returns (blocks, leftover_vertex_ids) where
     leftover vertices exceed the largest width (handled by the sorted path).
 
-    Rows are padded to a multiple of ``row_align`` for kernel-friendly grids.
+    Rows are padded to a whole number of the kernel's row tiles
+    (``louvain_scan.ops.padded_rows``).
     """
+    from repro.kernels.louvain_scan.ops import padded_rows  # lazy: Pallas
+
     indptr = np.asarray(graph.indptr)
     indices = np.asarray(graph.indices)
     weights = np.asarray(graph.weights)
@@ -282,7 +283,7 @@ def to_ell_blocks(
         if width == widths[0]:
             sel = np.where(deg <= width)[0]  # include isolated vertices
         lo = width
-        n_rows = int(np.ceil(max(len(sel), 1) / row_align) * row_align)
+        n_rows = padded_rows(len(sel), width)
         rows = np.full(n_rows, n_cap, dtype=np.int32)
         cols = np.full((n_rows, width), n_cap, dtype=np.int32)
         wmat = np.zeros((n_rows, width), dtype=np.float32)

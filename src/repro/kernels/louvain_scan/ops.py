@@ -36,20 +36,21 @@ def block_rows_for_width(width: int) -> int:
                      f"({max(_BLOCK_ROWS)})")
 
 
-def tile_rows(r: int, d: int) -> int:
-    """Rows per program for an (R, D) ELL block.
+def padded_rows(n: int, d: int) -> int:
+    """Rows of a width-``d`` ELL block holding ``n`` vertices.
 
-    The TPU takes a (rows, D) block only when rows is a multiple of 8 or
-    all of R.  ``to_ell_blocks`` pads R to a multiple of 8, so the answer is
-    the largest multiple of 8 that divides R within the width's cap; an R
-    that is not a multiple of 8 takes one whole-array block.
+    ``n`` rounded up to 8 (the TPU's sublane tile), then to a multiple of
+    ``tile_rows``: at most one tile of padding rows per width.
     """
-    if r % 8:
-        return r
-    rows = max(8, min(block_rows_for_width(d), r) // 8 * 8)
-    while r % rows:
-        rows -= 8
-    return rows
+    r8 = -(-max(n, 1) // 8) * 8
+    tile = min(block_rows_for_width(d), r8)
+    return -(-r8 // tile) * tile
+
+
+def tile_rows(r: int, d: int) -> int:
+    """Rows per program for an (R, D) ELL block: the width's cap, or all of
+    R when R is smaller.  ``padded_rows`` makes R a multiple of it."""
+    return min(block_rows_for_width(d), r)
 
 
 def prepare_ell_inputs(

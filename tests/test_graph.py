@@ -8,6 +8,7 @@ except ImportError:         # optional dev dep — see tests/_hypothesis_fallbac
     from _hypothesis_fallback import given, settings, st
 
 from repro.core.graph import CSRGraph, build_csr, from_networkx, to_ell_blocks
+from repro.kernels.louvain_scan.ops import padded_rows, tile_rows
 
 import networkx as nx
 
@@ -100,7 +101,7 @@ def test_ell_blocks_degree_bounds():
     assert all(deg[v] > widths[-1] for v in leftover)
 
 
-def _ell_blocks_loop(graph, widths, row_align=8):
+def _ell_blocks_loop(graph, widths):
     """Row-by-row reference of ``to_ell_blocks`` (the pre-vectorised loop)."""
     indptr = np.asarray(graph.indptr)
     indices = np.asarray(graph.indices)
@@ -114,7 +115,7 @@ def _ell_blocks_loop(graph, widths, row_align=8):
         if width == widths[0]:
             sel = np.where(deg <= width)[0]
         lo = width
-        n_rows = int(np.ceil(max(len(sel), 1) / row_align) * row_align)
+        n_rows = padded_rows(len(sel), width)
         rows = np.full(n_rows, n_cap, np.int32)
         cols = np.full((n_rows, width), n_cap, np.int32)
         wmat = np.zeros((n_rows, width), np.float32)
@@ -139,6 +140,17 @@ def test_ell_blocks_match_row_loop(seed, widths):
     for b, ref in zip(blocks, want):
         for got, exp in zip(b, ref):
             np.testing.assert_array_equal(np.asarray(got), exp)
+
+
+@pytest.mark.parametrize("width", [2, 16, 64, 256])
+def test_ell_rows_pad_to_whole_tiles(width):
+    """Every bucket size pads to whole kernel tiles that are multiples of 8,
+    with less than one tile (past the 8-row rounding) of padding."""
+    for n in [0, 1, 7, 8, 9, 63, 64, 65, 255, 256, 257, 264, 1000, 4103]:
+        rows = padded_rows(n, width)
+        tile = tile_rows(rows, width)
+        assert tile % 8 == 0 and rows % tile == 0
+        assert rows >= max(n, 1) and rows - tile < -(-max(n, 1) // 8) * 8
 
 
 def test_dedup_sums_match_sequential_add():

@@ -55,12 +55,15 @@ def test_resolve_compiles_at_stream_buffer_size(one_chip):
     assert "tpu_custom_call" in text
 
 
-# R = 264 at width 16 is the regression case: shrinking the block to any
-# divisor of R picked 132 rows, which the TPU refuses (not a multiple of 8).
-# R = 8 is the one-tile block of a small graph's sparse width buckets.
-@pytest.mark.parametrize("width,rows", [(16, 4096), (64, 1024), (256, 512),
-                                        (16, 264), (64, 8)])
-def test_fused_ell_compiles(one_chip, width, rows):
+# 264 rows at width 16 is the regression case: shrinking the block to a
+# divisor of R once picked 132 rows, which the TPU refuses (not a multiple
+# of 8); the block is now padded to two 256-row tiles.  8 rows is the
+# one-tile block of a small graph's sparse width buckets.
+@pytest.mark.parametrize("width,live_rows", [(16, 4096), (64, 1024),
+                                             (256, 512), (16, 264), (64, 8)])
+def test_fused_ell_compiles(one_chip, width, live_rows):
+    rows = ops.padded_rows(live_rows, width)
+
     def S(cols, dt):
         return jax.ShapeDtypeStruct((rows, cols), dt, sharding=one_chip)
 
