@@ -1,13 +1,12 @@
-"""Figure 6 analogue: phase split (local-moving / aggregation / others) and
-pass split (first pass vs rest) per graph — now per aggregation backend and
-per capacity-ladder setting, with per-pass timings as the committed
-machine-readable artifact.
+"""Figure 6 analogue: pass split (first pass vs rest) per graph, per
+aggregation backend and per capacity-ladder setting, with per-pass timings
+as the committed machine-readable artifact.
 
 ``BENCH_phase_split.json`` carries one row per (graph, agg_backend, ladder,
-pass) with ``local_move``/``aggregate``/``other`` seconds and the capacities
-the pass ran at, plus summary rows with the coarse-pass (pass >= 1) totals
-and the ladder's coarse-pass speedup — the before/after of the
-capacity-ladder PR is diffable straight from the artifact.
+pass) with the pass's seconds and the capacities it ran at, plus summary
+rows with the coarse-pass (pass >= 1) totals and the ladder's coarse-pass
+speedup.  The split of a pass into its phases is in a profiler trace: the
+pass loop's ``gve.*`` host spans (``repro.core.spans``).
 """
 
 from __future__ import annotations
@@ -42,10 +41,6 @@ def run(small: bool = True, repeats: int = 2):
             for ladder in (False, True):
                 cfg = LouvainConfig(use_ladder=ladder, agg_backend=backend)
                 res = _timed_run(g, cfg, repeats)
-                lm = sum(p.phase_seconds["local_move"] for p in res.passes)
-                ag = sum(p.phase_seconds["aggregate"] for p in res.passes)
-                ot = sum(p.phase_seconds["other"] for p in res.passes)
-                tot = max(lm + ag + ot, 1e-12)
                 all_p = max(sum(p.seconds for p in res.passes), 1e-12)
                 coarse = sum(p.seconds for p in res.passes[1:])
                 coarse_by_cfg[(backend, ladder)] = coarse
@@ -53,9 +48,6 @@ def run(small: bool = True, repeats: int = 2):
                     pass_rows.append({
                         "graph": gname, "agg_backend": backend,
                         "ladder": ladder, "pass": i,
-                        "local_move_s": round(p.phase_seconds["local_move"], 6),
-                        "aggregate_s": round(p.phase_seconds["aggregate"], 6),
-                        "other_s": round(p.phase_seconds["other"], 6),
                         "seconds": round(p.seconds, 6),
                         "n_cap": p.n_cap, "e_cap": p.e_cap,
                         "n_vertices": p.n_vertices,
@@ -64,9 +56,6 @@ def run(small: bool = True, repeats: int = 2):
                 summary.append({
                     "graph": gname, "agg_backend": backend, "ladder": ladder,
                     "passes": res.n_passes,
-                    "local_move_frac": round(lm / tot, 3),
-                    "aggregate_frac": round(ag / tot, 3),
-                    "other_frac": round(ot / tot, 3),
                     "first_pass_frac": round(res.passes[0].seconds / all_p, 3),
                     "coarse_pass_s": round(coarse, 6),
                 })
@@ -79,7 +68,6 @@ def run(small: bool = True, repeats: int = 2):
                     row["coarse_speedup_vs_no_ladder"] = round(
                         off / max(on, 1e-12), 2)
     emit_csv(summary, ["graph", "agg_backend", "ladder", "passes",
-                       "local_move_frac", "aggregate_frac", "other_frac",
                        "first_pass_frac", "coarse_pass_s",
                        "coarse_speedup_vs_no_ladder"])
     emit_json("phase_split", pass_rows,

@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import spans
 from repro.core.graph import CSRGraph
 
 
@@ -56,20 +57,21 @@ class EdgeBatch(NamedTuple):
 def make_edge_batch(src, dst, weight, n_cap: int,
                     b_cap: int | None = None) -> EdgeBatch:
     """Host-side batch builder; pads to ``b_cap`` with sentinel entries."""
-    src = np.asarray(src, dtype=np.int32)
-    dst = np.asarray(dst, dtype=np.int32)
-    weight = np.asarray(weight, dtype=np.float32)
-    b = len(src)
-    b_cap = int(b_cap if b_cap is not None else max(b, 1))
-    assert b_cap >= b, "batch capacity below batch size"
-    pad = np.full(b_cap - b, n_cap, np.int32)
-    return EdgeBatch(
-        src=jnp.asarray(np.concatenate([src, pad])),
-        dst=jnp.asarray(np.concatenate([dst, pad])),
-        weight=jnp.asarray(np.concatenate([weight,
-                                           np.zeros(b_cap - b, np.float32)])),
-        b_valid=jnp.asarray(b, dtype=np.int32),
-    )
+    with spans.span("make_edge_batch"):
+        src = np.asarray(src, dtype=np.int32)
+        dst = np.asarray(dst, dtype=np.int32)
+        weight = np.asarray(weight, dtype=np.float32)
+        b = len(src)
+        b_cap = int(b_cap if b_cap is not None else max(b, 1))
+        assert b_cap >= b, "batch capacity below batch size"
+        pad = np.full(b_cap - b, n_cap, np.int32)
+        return EdgeBatch(
+            src=jnp.asarray(np.concatenate([src, pad])),
+            dst=jnp.asarray(np.concatenate([dst, pad])),
+            weight=jnp.asarray(np.concatenate(
+                [weight, np.zeros(b_cap - b, np.float32)])),
+            b_valid=jnp.asarray(b, dtype=np.int32),
+        )
 
 
 def sort_reduce_apply_slots(all_src, all_dst, all_w, rank, is_batch,
@@ -235,16 +237,17 @@ def grow_graph_capacity(graph: CSRGraph, e_cap_new: int) -> CSRGraph:
     if e_cap_new < graph.e_cap:
         raise ValueError(f"cannot shrink e_cap {graph.e_cap} -> {e_cap_new}")
     n_cap = graph.n_cap
-    e = int(graph.e_valid)
+    e = int(spans.fetch("e_valid", graph.e_valid))
     pad_i = np.full(e_cap_new - e, n_cap, np.int32)
     pad_w = np.zeros(e_cap_new - e, np.float32)
     return CSRGraph(
         indptr=graph.indptr,
         indices=jnp.asarray(np.concatenate(
-            [np.asarray(graph.indices)[:e], pad_i])),
+            [spans.fetch("indices", graph.indices)[:e], pad_i])),
         weights=jnp.asarray(np.concatenate(
-            [np.asarray(graph.weights)[:e], pad_w])),
-        src=jnp.asarray(np.concatenate([np.asarray(graph.src)[:e], pad_i])),
+            [spans.fetch("weights", graph.weights)[:e], pad_w])),
+        src=jnp.asarray(np.concatenate(
+            [spans.fetch("src", graph.src)[:e], pad_i])),
         n_valid=graph.n_valid,
         e_valid=graph.e_valid,
     )
@@ -264,13 +267,18 @@ def apply_edge_batch(graph: CSRGraph, batch: EdgeBatch, *,
     selects the group-resolve implementation (see
     ``sort_reduce_apply_slots``).
     """
-    out, touched, e_new = _apply_edge_batch(graph, batch, backend=backend)
-    if int(e_new) > graph.e_cap:
-        if not grow:
-            raise ValueError(
-                f"edge batch overflows capacity: {int(e_new)} live directed "
-                f"slots > e_cap={graph.e_cap}")
-        grown = grow_graph_capacity(
-            graph, max(2 * graph.e_cap, int(e_new)))
-        out, touched, e_new = _apply_edge_batch(grown, batch, backend=backend)
-    return out, touched
+    with spans.span("apply"):
+        out, touched, e_new = _apply_edge_batch(graph, batch,
+                                                backend=backend)
+        e_new = int(spans.fetch("e_new", e_new))
+        if e_new > graph.e_cap:
+            if not grow:
+                raise ValueError(
+                    f"edge batch overflows capacity: {e_new} live directed "
+                    f"slots > e_cap={graph.e_cap}")
+            with spans.span("apply.grow", e_cap=graph.e_cap):
+                grown = grow_graph_capacity(
+                    graph, max(2 * graph.e_cap, e_new))
+                out, touched, _ = _apply_edge_batch(grown, batch,
+                                                    backend=backend)
+        return out, touched

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import spans
 from repro.core.delta import EdgeBatch, apply_edge_batch
 from repro.core.engine import affected_frontier, normalize_screening
 from repro.core.graph import CSRGraph
@@ -127,6 +128,15 @@ def louvain_dynamic(
     property is that modularity tracks a cold recompute while
     ``frontier_size`` stays a small fraction of n.
     """
+    with spans.span("louvain_dynamic"):
+        return _louvain_dynamic(graph, batches, prev, config, screening,
+                                track_modularity, grow_capacity,
+                                apply_backend)
+
+
+def _louvain_dynamic(graph, batches, prev, config, screening,
+                     track_modularity, grow_capacity,
+                     apply_backend) -> DynamicResult:
     t_start = time.perf_counter()
     n_cap = graph.n_cap
     screen_mode = normalize_screening(screening)
@@ -141,7 +151,8 @@ def louvain_dynamic(
     # a sync inside the stream loop, so collect the lazy scalars and fill the
     # stats in one host transfer after the stream.
     touched_counts: List[jax.Array] = []
-    n_comms = int(len(np.unique(membership[: int(graph.n_valid)])))
+    n = int(spans.fetch("n_vertices", graph.n_valid))
+    n_comms = int(len(np.unique(membership[:n])))
     for batch in batches:
         t0 = time.perf_counter()
         graph, touched = apply_edge_batch(graph, batch, grow=grow_capacity,
@@ -150,20 +161,21 @@ def louvain_dynamic(
 
         frontier = None
         if screen_mode is not None:
-            frontier = affected_frontier(
-                touched, jnp.asarray(membership), graph.n_valid,
-                screen_mode)
+            with spans.span("screen"):
+                frontier = affected_frontier(
+                    touched, jnp.asarray(membership), graph.n_valid,
+                    screen_mode)
         res: LouvainResult = louvain(
             graph, config, init_membership=membership,
             init_frontier=frontier)
         t2 = time.perf_counter()
 
-        n = int(graph.n_valid)
+        n = int(spans.fetch("n_vertices", graph.n_valid))
         membership = _pad_membership(res.membership, n_cap)
         n_comms = res.n_communities
         touched_counts.append(jnp.sum(touched))
         stats.append(BatchUpdateStats(
-            batch_size=int(batch.b_valid),
+            batch_size=int(spans.fetch("b_valid", batch.b_valid)),
             n_touched=-1,  # filled from touched_counts after the stream
             frontier_size=res.passes[0].frontier_size if res.passes else 0,
             n_vertices=n,
@@ -174,9 +186,8 @@ def louvain_dynamic(
             if track_modularity else None,
         ))
     for s, cnt in zip(stats, touched_counts):
-        s.n_touched = int(cnt)
+        s.n_touched = int(spans.fetch("n_touched", cnt))
 
-    n = int(graph.n_valid)
     return DynamicResult(
         graph=graph,
         membership=membership[:n].copy(),

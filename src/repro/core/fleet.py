@@ -571,7 +571,6 @@ class FleetRouter:
                     n_vertices=nv_i,
                     dq_sum=float(dq_sum[i]),
                     seconds=p.seconds,
-                    phase_seconds={},
                     frontier_size=int(frontier_n[i]),
                     n_cap=spec.n_pad, e_cap=spec.e_per_shard * spec.n_shards,
                     screening=p.mode, scan_backend="sharded",
@@ -670,7 +669,7 @@ class FleetRouter:
             iterations=sum(r["iterations"] for r in pstats),
             n_communities=nc, n_vertices=n_live,
             dq_sum=sum(r["dq_sum"] for r in pstats),
-            seconds=0.0, phase_seconds={},
+            seconds=0.0,
             frontier_size=int(np.asarray(jnp.sum(frontier)))
             if frontier is not None else n_live,
             n_cap=spec_new.n_pad,

@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import spans
+
 
 class CSRGraph(NamedTuple):
     """Padded CSR graph.  All fields are jax arrays unless noted.
@@ -268,10 +270,10 @@ def to_ell_blocks(
     """
     from repro.kernels.louvain_scan.ops import padded_rows  # lazy: Pallas
 
-    indptr = np.asarray(graph.indptr)
-    indices = np.asarray(graph.indices)
-    weights = np.asarray(graph.weights)
-    n = int(graph.n_valid)
+    indptr = spans.fetch("ell.indptr", graph.indptr)
+    indices = spans.fetch("ell.indices", graph.indices)
+    weights = spans.fetch("ell.weights", graph.weights)
+    n = int(spans.fetch("ell.n_vertices", graph.n_valid))
     n_cap = graph.n_cap
     deg = indptr[1 : n + 1] - indptr[:n]
 

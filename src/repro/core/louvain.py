@@ -2,8 +2,11 @@
 
 The pass loop runs on the host (graph capacities are static, so every phase is
 jit-compiled exactly once and reused across passes — the JAX realization of
-the paper's preallocated ping-pong buffers).  All paper parameters are exposed
-with the paper's defaults:
+the paper's preallocated ping-pong buffers).  Its stretches of work are host
+spans and its device reads named syncs (``repro.core.spans``): ``gve.pass``
+holds ``gve.move``, ``gve.refine``, ``gve.renumber`` and ``gve.aggregate``,
+and a ``gve.pass.counts`` counter follows each pass.  All paper parameters
+are exposed with the paper's defaults:
 
     MAX_PASSES=10, MAX_ITERATIONS=20, initial tolerance 0.01,
     TOLERANCE_DROP=10, aggregation tolerance 0.8, vertex pruning on.
@@ -32,6 +35,7 @@ from repro.configs.louvain_arch import (COMPACT_WORK_FRAC, compact_work_cap,
                                         resolve_agg_backend,
                                         resolve_coarse_capacity,
                                         resolve_scan_backend)
+from repro.core import spans
 from repro.core.aggregate import aggregate_graph, renumber_communities
 from repro.core.engine import affected_frontier
 from repro.core.graph import CSRGraph, count_trace, rebucket_capacity
@@ -135,7 +139,6 @@ class PassStats:
     n_vertices: int
     dq_sum: float
     seconds: float
-    phase_seconds: dict
     modularity: Optional[float] = None
     frontier_size: Optional[int] = None  # seed-frontier size (delta screening)
     n_cap: Optional[int] = None          # capacities the pass ran at
@@ -377,15 +380,19 @@ def louvain(
     sort-reduce chain or the fused Pallas kernel) — memberships are
     bit-identical across ladder tiers and aggregation backends.
     """
+    with spans.span("louvain"):
+        return _louvain(graph, config, init_membership, init_frontier)
+
+
+def _louvain(graph, config, init_membership, init_frontier) -> LouvainResult:
     t_start = time.perf_counter()
     n_cap = graph.n_cap
-    n = int(graph.n_valid)
+    n = int(spans.fetch("n_vertices", graph.n_valid))
     global_comm = jnp.arange(n_cap, dtype=jnp.int32)
 
     g = graph
     tol = float(config.initial_tolerance)
     passes: List[PassStats] = []
-    n_comms_final = n
     agg_backend = resolve_agg_backend(config.agg_backend)
     if config.refine not in ("none", "leiden"):
         raise ValueError(f"refine must be 'none' or 'leiden', "
@@ -409,34 +416,24 @@ def louvain(
         if fr.shape[0] < n_cap + 1:
             fr = jnp.concatenate(
                 [fr, jnp.zeros(n_cap + 1 - fr.shape[0], bool)])
-    if init_membership is not None:
-        mem = np.asarray(init_membership, dtype=np.int32)
-        if len(mem) < n_cap + 1:   # pad (n,) / (n_cap,) inputs to capacity
-            mem = np.concatenate(
-                [mem, np.full(n_cap + 1 - len(mem), n_cap, np.int32)])
-        warm_comm0, warm_sigma0, warm_frontier0 = warm_init(
-            g, jnp.asarray(mem), fr)
-        frontier_size0 = int(jnp.sum(warm_frontier0))
-    elif fr is not None:
-        # Screened frontier over a cold singleton start: still honored.
-        warm_comm0, warm_sigma0, frontier0_all = singleton_init(g)
-        warm_frontier0 = fr & frontier0_all
-        frontier_size0 = int(jnp.sum(warm_frontier0))
+    if init_membership is not None or fr is not None:
+        with spans.span("warm_start"):
+            if init_membership is not None:
+                mem = np.asarray(init_membership, dtype=np.int32)
+                if len(mem) < n_cap + 1:   # pad (n,) / (n_cap,) to capacity
+                    mem = np.concatenate(
+                        [mem, np.full(n_cap + 1 - len(mem), n_cap, np.int32)])
+                warm_comm0, warm_sigma0, warm_frontier0 = warm_init(
+                    g, jnp.asarray(mem), fr)
+            else:
+                # Screened frontier over a cold singleton start: still honored.
+                warm_comm0, warm_sigma0, frontier0_all = singleton_init(g)
+                warm_frontier0 = fr & frontier0_all
+            frontier_size0 = int(spans.fetch("frontier",
+                                             jnp.sum(warm_frontier0)))
 
     for p in range(config.max_passes):
         t0 = time.perf_counter()
-        if p == 0 and warm_comm0 is not None:
-            comm0, sigma0, frontier0 = warm_comm0, warm_sigma0, warm_frontier0
-            pass_frontier = frontier_size0
-        elif leiden_warm is not None:
-            # Leiden pass semantics: the coarse graph's vertices are the
-            # REFINED communities, so the next pass resumes from the outer
-            # partition expressed on them (Q matches the reported outer Q).
-            comm0, sigma0, frontier0 = warm_init(g, jnp.asarray(leiden_warm))
-            pass_frontier = None
-        else:
-            comm0, sigma0, frontier0 = singleton_init(g)
-            pass_frontier = None
         # A *screened* frontier is active only on pass 0 with init_frontier;
         # warm-only starts re-scan all vertices, so compaction buys nothing.
         frontier_frac = (frontier_size0 / max(n, 1)
@@ -444,115 +441,144 @@ def louvain(
         backend = resolve_scan_backend(
             config.scan_backend, use_ell_kernel=config.use_ell_kernel,
             frontier_frac=frontier_frac)
-        if ell_family:
-            comm, iters, dq_sum = ell_move.move_phase_ell(
-                g, jnp.float32(tol), max_iterations=config.max_iterations,
-                use_pruning=config.use_pruning,
-                gate_fraction=config.gate_fraction, widths=config.ell_widths,
-                comm0=comm0, sigma0=sigma0, frontier0=frontier0,
-                fused=backend == "ell_fused")
-        else:
-            comm, iters, dq_sum = _move_phase(
-                g, comm0, sigma0, frontier0, jnp.float32(tol),
-                max_iterations=config.max_iterations,
-                use_pruning=config.use_pruning,
-                gate_fraction=config.gate_fraction,
-                work_cap=(compact_work_cap(g.e_cap, config.compact_cap_frac)
-                          if backend == "compact" else 0))
-        iters = int(iters)
-        t1a = time.perf_counter()
-
-        refine_iters = None
-        outer_ren = None
-        if refine_on:
-            if ell_family:
-                refined, r_it, _r_dq = ell_move.move_phase_ell(
-                    g, jnp.float32(tol),
-                    max_iterations=config.max_iterations,
-                    use_pruning=config.use_pruning,
-                    gate_fraction=config.gate_fraction,
-                    widths=config.ell_widths,
-                    fused=backend == "ell_fused", refine_outer=comm)
-            else:
-                refined, r_it, _r_dq = _refine_phase(
-                    g, comm, jnp.float32(tol),
-                    max_iterations=config.max_iterations,
-                    use_pruning=config.use_pruning,
-                    gate_fraction=config.gate_fraction)
-            refine_iters = int(r_it)
-        t1 = time.perf_counter()
-
-        if refine_on:
-            # Two folds off the SAME pre-pass global_comm: the outer fold is
-            # what this pass reports, the refined fold is what aggregation
-            # (and the dendrogram chain) follows.
-            outer_ren, n_outer, outer_fold = _renumber_and_fold(
-                comm, g.n_valid, jnp.int32(g.n_cap), global_comm)
-            comm_ren, n_comms, folded = _renumber_and_fold(
-                refined, g.n_valid, jnp.int32(g.n_cap), global_comm)
-            level = outer_fold
-            n_report = int(n_outer)
-        else:
-            comm_ren, n_comms, folded = _renumber_and_fold(
-                comm, g.n_valid, jnp.int32(g.n_cap), global_comm)
-            level = folded
-            n_report = int(n_comms)
-        global_comm = folded
-        n_comms_i = int(n_comms)        # aggregation granularity (refined)
-        n_verts_i = int(g.n_valid)
-        levels.append(np.asarray(level[:n]))
-        t2 = time.perf_counter()
-
-        q_now = float(modularity(graph, jnp.concatenate(
-            [level, jnp.asarray([n_cap], jnp.int32)]))) \
-            if config.track_modularity else None
-
-        converged = iters <= 1                       # Alg. 1 line 7
-        low_shrink = n_report / max(n_verts_i, 1) > config.aggregation_tolerance  # line 9
-
+        work_cap = (compact_work_cap(g.e_cap, config.compact_cap_frac)
+                    if backend == "compact" else 0)
         pass_caps = (g.n_cap, g.e_cap)
-        if not (converged or low_shrink or p == config.max_passes - 1):
-            g = _aggregate_phase(g, comm_ren, n_comms, backend=agg_backend)
-            if config.use_ladder:
-                # Ladder: re-bucket the coarse graph down to the smallest
-                # power-of-two tier that fits it, so the NEXT pass's phases
-                # run (and jit-cache) at coarse capacity.
-                n_cap_new, e_cap_new = resolve_coarse_capacity(
-                    n_comms_i, int(g.e_valid), g.n_cap, g.e_cap)
-                if (n_cap_new, e_cap_new) != (g.n_cap, g.e_cap):
-                    g = rebucket_capacity(g, n_cap_new=n_cap_new,
-                                          e_cap_new=e_cap_new)
-            if refine_on:
-                warm_flat = np.asarray(_leiden_warm_membership(
-                    comm_ren, outer_ren, jnp.int32(n_verts_i),
-                    n_comms))[:n_comms_i]
-                leiden_warm = pad_membership(warm_flat, g.n_cap)
-            t3 = time.perf_counter()
-            agg_s = t3 - t2
-        else:
-            agg_s = 0.0
+        with spans.span("pass", **{"pass": p}, n_cap=g.n_cap,
+                        e_cap=g.e_cap, backend=backend):
+            with spans.span("move"):
+                if p == 0 and warm_comm0 is not None:
+                    comm0, sigma0, frontier0 = (warm_comm0, warm_sigma0,
+                                                warm_frontier0)
+                    pass_frontier = frontier_size0
+                elif leiden_warm is not None:
+                    # Leiden pass semantics: the coarse graph's vertices are
+                    # the REFINED communities, so the next pass resumes from
+                    # the outer partition expressed on them (Q matches the
+                    # reported outer Q).
+                    comm0, sigma0, frontier0 = warm_init(
+                        g, jnp.asarray(leiden_warm))
+                    pass_frontier = None
+                else:
+                    comm0, sigma0, frontier0 = singleton_init(g)
+                    pass_frontier = None
+                if ell_family:
+                    comm, iters, dq_sum = ell_move.move_phase_ell(
+                        g, jnp.float32(tol),
+                        max_iterations=config.max_iterations,
+                        use_pruning=config.use_pruning,
+                        gate_fraction=config.gate_fraction,
+                        widths=config.ell_widths,
+                        comm0=comm0, sigma0=sigma0, frontier0=frontier0,
+                        fused=backend == "ell_fused")
+                else:
+                    comm, iters, dq_sum = _move_phase(
+                        g, comm0, sigma0, frontier0, jnp.float32(tol),
+                        max_iterations=config.max_iterations,
+                        use_pruning=config.use_pruning,
+                        gate_fraction=config.gate_fraction,
+                        work_cap=work_cap)
+                iters = int(spans.fetch("iters", iters))
 
-        passes.append(PassStats(
-            iterations=iters, n_communities=n_report, n_vertices=n_verts_i,
-            dq_sum=float(dq_sum), seconds=time.perf_counter() - t0,
-            phase_seconds={"local_move": t1a - t0,
-                           "other": t2 - t1, "aggregate": agg_s,
-                           **({"refine": t1 - t1a} if refine_on else {})},
-            modularity=q_now,
-            frontier_size=pass_frontier if pass_frontier is not None
-            else n_verts_i,
-            n_cap=pass_caps[0], e_cap=pass_caps[1],
-            refine_iterations=refine_iters,
-            n_refined=n_comms_i if refine_on else None,
-        ))
-        n_comms_final = n_report
+            refine_iters = None
+            outer_ren = None
+            if refine_on:
+                with spans.span("refine"):
+                    if ell_family:
+                        refined, r_it, _r_dq = ell_move.move_phase_ell(
+                            g, jnp.float32(tol),
+                            max_iterations=config.max_iterations,
+                            use_pruning=config.use_pruning,
+                            gate_fraction=config.gate_fraction,
+                            widths=config.ell_widths,
+                            fused=backend == "ell_fused", refine_outer=comm)
+                    else:
+                        refined, r_it, _r_dq = _refine_phase(
+                            g, comm, jnp.float32(tol),
+                            max_iterations=config.max_iterations,
+                            use_pruning=config.use_pruning,
+                            gate_fraction=config.gate_fraction)
+                    refine_iters = int(spans.fetch("refine_iters", r_it))
+
+            with spans.span("renumber"):
+                if refine_on:
+                    # Two folds off the SAME pre-pass global_comm: the outer
+                    # fold is what this pass reports, the refined fold is
+                    # what aggregation (and the dendrogram chain) follows.
+                    outer_ren, n_outer, outer_fold = _renumber_and_fold(
+                        comm, g.n_valid, jnp.int32(g.n_cap), global_comm)
+                    comm_ren, n_comms, folded = _renumber_and_fold(
+                        refined, g.n_valid, jnp.int32(g.n_cap), global_comm)
+                    level = outer_fold
+                    n_report = int(spans.fetch("n_outer", n_outer))
+                    # aggregation granularity (refined)
+                    n_comms_i = int(spans.fetch("n_comms", n_comms))
+                else:
+                    comm_ren, n_comms, folded = _renumber_and_fold(
+                        comm, g.n_valid, jnp.int32(g.n_cap), global_comm)
+                    level = folded
+                    n_report = n_comms_i = int(spans.fetch("n_comms",
+                                                           n_comms))
+                global_comm = folded
+                n_verts_i = int(spans.fetch("n_vertices", g.n_valid))
+                levels.append(spans.fetch("level", level[:n]))
+
+            q_now = float(spans.fetch("modularity", modularity(
+                graph, jnp.concatenate(
+                    [level, jnp.asarray([n_cap], jnp.int32)])))) \
+                if config.track_modularity else None
+
+            converged = iters <= 1                       # Alg. 1 line 7
+            low_shrink = (n_report / max(n_verts_i, 1)
+                          > config.aggregation_tolerance)    # line 9
+
+            if not (converged or low_shrink or p == config.max_passes - 1):
+                with spans.span("aggregate"):
+                    g = _aggregate_phase(g, comm_ren, n_comms,
+                                         backend=agg_backend)
+                    if config.use_ladder:
+                        # Ladder: re-bucket the coarse graph down to the
+                        # smallest power-of-two tier that fits it, so the
+                        # NEXT pass's phases run (and jit-cache) at coarse
+                        # capacity.
+                        n_cap_new, e_cap_new = resolve_coarse_capacity(
+                            n_comms_i, int(spans.fetch("e_valid", g.e_valid)),
+                            g.n_cap, g.e_cap)
+                        if (n_cap_new, e_cap_new) != (g.n_cap, g.e_cap):
+                            g = rebucket_capacity(g, n_cap_new=n_cap_new,
+                                                  e_cap_new=e_cap_new)
+                    if refine_on:
+                        warm_flat = spans.fetch(
+                            "leiden_warm", _leiden_warm_membership(
+                                comm_ren, outer_ren, jnp.int32(n_verts_i),
+                                n_comms))[:n_comms_i]
+                        leiden_warm = pad_membership(warm_flat, g.n_cap)
+
+            stats = PassStats(
+                iterations=iters, n_communities=n_report,
+                n_vertices=n_verts_i,
+                dq_sum=float(spans.fetch("dq_sum", dq_sum)),
+                seconds=time.perf_counter() - t0,
+                modularity=q_now,
+                frontier_size=pass_frontier if pass_frontier is not None
+                else n_verts_i,
+                n_cap=pass_caps[0], e_cap=pass_caps[1],
+                refine_iterations=refine_iters,
+                n_refined=n_comms_i if refine_on else None,
+            )
+        passes.append(stats)
+        spans.mark("pass.counts", **{"pass": p}, sweeps=iters,
+                   slots=work_cap or pass_caps[1],
+                   n_vertices=n_verts_i, n_communities=n_report,
+                   frontier=stats.frontier_size)
         if converged or low_shrink:
             break
         tol = tol / config.tolerance_drop            # line 13 threshold scaling
 
     # With refinement the dendrogram chain (global_comm) follows the REFINED
     # partitions; the reported membership is the last pass's OUTER level.
-    membership = levels[-1] if levels else np.asarray(global_comm[:n])
+    membership = (levels[-1] if levels
+                  else spans.fetch("level", global_comm[:n]))
     return LouvainResult(
         membership=membership,
         n_communities=int(len(np.unique(membership))),
@@ -570,7 +596,7 @@ def membership_modularity(graph: CSRGraph, membership) -> float:
         jnp.full((graph.n_cap + 1 - len(membership),), graph.n_cap,
                  jnp.int32),
     ])
-    return float(modularity(graph, comm))
+    return float(spans.fetch("modularity", modularity(graph, comm)))
 
 
 def louvain_modularity(graph: CSRGraph, result: LouvainResult) -> float:

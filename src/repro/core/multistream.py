@@ -447,7 +447,7 @@ def louvain_dynamic_batched(
     def _step_stat(mode, mode_down, iters_max, fsize_max, nv_max):
         return PassStats(
             iterations=int(iters_max), n_communities=0, n_vertices=nv_max,
-            dq_sum=0.0, seconds=0.0, phase_seconds={},
+            dq_sum=0.0, seconds=0.0,
             frontier_size=int(fsize_max), n_cap=n_cap, e_cap=e_cap,
             screening=mode, scan_backend=scan_used,
             downgraded=bool(mode_down or scan_down))

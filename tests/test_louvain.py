@@ -169,6 +169,7 @@ def test_isolated_vertices_stay_put():
 
 # -- Leiden-style refinement --------------------------------------------------
 
+from _spans import inside, named, traced  # noqa: E402
 from _oracle import (disconnected_communities, modularity_np,  # noqa: E402
                      oracle_graph_slots, refine_oracle)
 
@@ -255,11 +256,14 @@ def test_refine_oracle_properties():
 
 def test_refine_pass_stats_populated():
     g = _badly_connected_graph()
-    res = louvain(g, LouvainConfig(refine="leiden"))
+    res, events = traced(louvain, g, LouvainConfig(refine="leiden"))
     assert all(p.refine_iterations is not None for p in res.passes)
     assert all(p.n_refined is not None and p.n_refined >= p.n_communities
                for p in res.passes)
-    assert all("refine" in p.phase_seconds for p in res.passes)
+    passes = named(events, "gve.pass")
+    assert len(passes) == len(res.passes)
+    assert all(any(inside(r, p) for r in named(events, "gve.refine"))
+               for p in passes)
     res_none = louvain(g)
     assert all(p.refine_iterations is None and p.n_refined is None
                for p in res_none.passes)

@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _spans import inside, named, traced
 from repro.core.louvain import LouvainConfig, louvain, louvain_modularity
 from repro.data import powerlaw_cluster, rmat_graph, sbm_graph
 
@@ -42,12 +43,18 @@ def test_sbm_quality_tracks_planted_q():
 
 def test_pass_stats_structure():
     g = rmat_graph(8, edge_factor=4, seed=3)
-    res = louvain(g, LouvainConfig(track_modularity=True))
+    res, events = traced(louvain, g, LouvainConfig(track_modularity=True))
     assert res.passes
-    for p in res.passes:
+    pass_spans = named(events, "gve.pass")
+    assert len(pass_spans) == len(res.passes)
+    for i, (p, span) in enumerate(zip(res.passes, pass_spans)):
         assert p.iterations >= 1
         assert p.n_communities <= p.n_vertices
-        assert set(p.phase_seconds) == {"local_move", "other", "aggregate"}
+        phases = {e.name for e in events if e is not span and inside(e, span)
+                  and e.name in ("gve.move", "gve.renumber", "gve.aggregate")}
+        last = i == len(res.passes) - 1
+        assert phases == {"gve.move", "gve.renumber"} | (
+            set() if last else {"gve.aggregate"})
         assert p.modularity is None or np.isfinite(p.modularity)
     # monotone coarsening
     sizes = [p.n_vertices for p in res.passes]
