@@ -31,37 +31,29 @@ _NEG_INF = -jnp.inf
 
 __all__ = ["CompactSortReduceScanner", "MoveState", "SortReduceScanner",
            "best_moves", "best_moves_slots", "compact_best_moves",
-           "gather_frontier_slots", "louvain_move",
-           "scan_communities_sorted"]
-
-
-def scan_communities_sorted(
-    graph: CSRGraph, comm: jax.Array
-) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """Group edge slots by (src, C[dst]) and compute K_{i->c} per slot.
-
-    Returns (order, s_src, s_c, k_i_to_c) where arrays are in sorted slot
-    order.  Self-loop slots contribute 0 (K_{i->c} excludes self edges).
-    """
-    return _scan_communities_slots(graph.src, graph.indices, graph.weights,
-                                   comm)
+           "gather_frontier_slots", "louvain_move"]
 
 
 def _scan_communities_slots(src, dst, w, comm):
-    """``scan_communities_sorted`` over arbitrary directed-slot arrays."""
+    """Group directed slots by (src, C[dst]); K_{i->c} per slot.
+
+    Returns (s_src, s_c, k_i_to_c) in sorted slot order.  Self-loop slots
+    contribute 0 (K_{i->c} excludes self edges).  The sort carries the
+    weights as a payload and is stable, so each group adds its weights in
+    slot order.
+    """
     cdst = comm[dst]
-    order = jnp.lexsort((cdst, src))  # primary: src, secondary: community
-    s_src = src[order]
-    s_dst = dst[order]
-    s_c = cdst[order]
-    s_w = jnp.where(s_src == s_dst, 0.0, w[order])
+    w0 = jnp.where(src == dst, 0.0, w)
+    # primary: src, secondary: community
+    s_src, s_c, s_w = jax.lax.sort((src, cdst, w0), num_keys=2,
+                                   is_stable=True)
 
     prev_src = jnp.concatenate([jnp.full((1,), -1, jnp.int32), s_src[:-1]])
     prev_c = jnp.concatenate([jnp.full((1,), -1, jnp.int32), s_c[:-1]])
     new_group = (s_src != prev_src) | (s_c != prev_c)
     gid = jnp.cumsum(new_group.astype(jnp.int32)) - 1
     group_w = jax.ops.segment_sum(s_w, gid, num_segments=src.shape[0])
-    return order, s_src, s_c, group_w[gid]
+    return s_src, s_c, group_w[gid]
 
 
 def best_moves_slots(
@@ -80,9 +72,9 @@ def best_moves_slots(
     The slot arrays may be the graph's full ``e_cap`` layout or any
     compacted subset of it (dead slots hold the sentinel ``n_cap``); a
     vertex whose live slots are ALL present gets exactly the full-scan
-    answer — compaction preserves slot order, the lexsort is stable, and
-    the per-group reductions therefore add the same weights in the same
-    order, so the result is bit-identical, not just numerically close.
+    answer — compaction preserves slot order, the sort is declared stable,
+    and the per-group reductions therefore add the same weights in the
+    same order, so the result is bit-identical, not just numerically close.
     """
     # K_{i -> own community} — direct segment-sum, no sort needed.
     own = (comm[dst] == comm[src]) & (dst != src)
@@ -90,7 +82,7 @@ def best_moves_slots(
         jnp.where(own, w, 0.0), src, num_segments=n_cap + 1
     )
 
-    _, s_src, s_c, k_i_to_c = _scan_communities_slots(src, dst, w, comm)
+    s_src, s_c, k_i_to_c = _scan_communities_slots(src, dst, w, comm)
     c_own = comm[s_src]
     dq = delta_modularity(
         k_i_to_c, k_to_own[s_src], k[s_src], sigma[s_c], sigma[c_own], m
