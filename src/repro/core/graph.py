@@ -13,6 +13,8 @@ Conventions (the slot contract every module in ``repro.core`` assumes):
   - self loop {i,i}                 -> ONE slot (i,i,w)
   - K_i  = sum of slot weights out of i          (row sum of adjacency)
   - m    = (sum of all slot weights) / 2
+  - src  = each row id repeated by its degree, in row order, then the
+           padding slots (``local_move.slot_counts`` counts slots by it)
 These are conserved exactly under community coarsening.
 """
 

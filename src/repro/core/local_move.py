@@ -24,23 +24,24 @@ import jax.numpy as jnp
 from repro.core.engine import (ConstrainedScanner, EngineConfig, MoveEngine,
                                MoveState, ReplicatedScannerBase,
                                mask_cross_outer_slots, sanitize_outer)
-from repro.core.graph import CSRGraph
+from repro.core.graph import CSRGraph, count_trace
 from repro.core.modularity import delta_modularity
 
 _NEG_INF = -jnp.inf
 
 __all__ = ["CompactSortReduceScanner", "MoveState", "SortReduceScanner",
            "best_moves", "best_moves_slots", "compact_best_moves",
-           "gather_frontier_slots", "louvain_move"]
+           "gather_frontier_slots", "louvain_move", "slot_counts",
+           "spread_to_slots"]
 
 
 def _scan_communities_slots(src, dst, w, comm):
     """Group directed slots by (src, C[dst]); K_{i->c} per slot.
 
-    Returns (s_src, s_c, k_i_to_c) in sorted slot order.  Self-loop slots
-    contribute 0 (K_{i->c} excludes self edges).  The sort carries the
-    weights as a payload and is stable, so each group adds its weights in
-    slot order.
+    Returns (s_src, s_c, s_w, k_i_to_c) in sorted slot order.  Self-loop
+    slots carry weight 0 (K_{i->c} excludes self edges).  The sort carries
+    the weights as a payload and is stable, so each group adds its weights
+    in slot order.
     """
     cdst = comm[dst]
     w0 = jnp.where(src == dst, 0.0, w)
@@ -53,7 +54,54 @@ def _scan_communities_slots(src, dst, w, comm):
     new_group = (s_src != prev_src) | (s_c != prev_c)
     gid = jnp.cumsum(new_group.astype(jnp.int32)) - 1
     group_w = jax.ops.segment_sum(s_w, gid, num_segments=src.shape[0])
-    return s_src, s_c, group_w[gid]
+    return s_src, s_c, s_w, group_w[gid]
+
+
+def slot_counts(graph: CSRGraph, keep: jax.Array | None = None,
+                e: int | None = None) -> jax.Array:
+    """(n_cap + 1,) int32 slots per source vertex of a CSR-ordered slot list.
+
+    The degrees of the vertices in ``keep`` ((n_cap + 1,) bool; all by
+    default), with the sentinel row holding the rest of the list's ``e``
+    slots (default ``e_cap``): the graph's own list, or its compaction by
+    ``gather_frontier_slots`` when nothing overflowed.
+    """
+    deg = graph.degrees()
+    if keep is not None:
+        deg = jnp.where(keep[:-1], deg, 0)
+    e = graph.e_cap if e is None else e
+    return jnp.concatenate([deg, e - jnp.sum(deg, keepdims=True)])
+
+
+def spread_to_slots(counts: jax.Array, e: int,
+                    *values: jax.Array) -> Tuple[jax.Array, ...]:
+    """Per-vertex values at every slot of a source-sorted slot list.
+
+    ``counts`` (n_cap + 1,) holds each vertex's number of slots, summing to
+    the list's length ``e`` (the sentinel row holds the pads); each value
+    is (n_cap + 1,) float32, int32 or bool.  Returns one (e,) array per
+    value, equal bit for bit to ``value[s_src]`` for the list's
+    non-decreasing source column ``s_src``.  A difference array places each
+    run: the value's int32 bits are added at the run's head and subtracted
+    at its end, and one wrapping int32 cumulative sum spreads them over the
+    run.  That is a scatter of 2 (n_cap + 1) updates and one pass over e,
+    not an e-sized gather.  Empty runs add nothing: their index is e,
+    which is dropped.
+    """
+    bits = jnp.stack([
+        jax.lax.bitcast_convert_type(v, jnp.int32)
+        if v.dtype == jnp.float32 else v.astype(jnp.int32) for v in values])
+    head = jnp.cumsum(counts) - counts
+    live = counts > 0
+    at = jnp.concatenate([jnp.where(live, head, e),
+                          jnp.where(live, head + counts, e)])
+    diff = jnp.zeros((bits.shape[0], e), jnp.int32).at[:, at].add(
+        jnp.concatenate([bits, -bits], axis=1), mode="drop")
+    out = jnp.cumsum(diff, axis=1)
+    return tuple(
+        jax.lax.bitcast_convert_type(o, jnp.float32)
+        if v.dtype == jnp.float32 else o.astype(v.dtype)
+        for v, o in zip(values, out))
 
 
 def best_moves_slots(
@@ -66,6 +114,7 @@ def best_moves_slots(
     frontier: jax.Array,
     m: jax.Array,
     n_cap: int,
+    counts: jax.Array | None = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Per-vertex (best community, best dQ) from a directed-slot list.
 
@@ -75,24 +124,33 @@ def best_moves_slots(
     answer — compaction preserves slot order, the sort is declared stable,
     and the per-group reductions therefore add the same weights in the
     same order, so the result is bit-identical, not just numerically close.
-    """
-    # K_{i -> own community} — direct segment-sum, no sort needed.
-    own = (comm[dst] == comm[src]) & (dst != src)
-    k_to_own = jax.ops.segment_sum(
-        jnp.where(own, w, 0.0), src, num_segments=n_cap + 1
-    )
 
-    s_src, s_c, k_i_to_c = _scan_communities_slots(src, dst, w, comm)
-    c_own = comm[s_src]
-    dq = delta_modularity(
-        k_i_to_c, k_to_own[s_src], k[s_src], sigma[s_c], sigma[c_own], m
-    )
-    valid = (s_c != c_own) & (s_src != n_cap) & (s_c != n_cap) & frontier[s_src]
+    ``counts`` (n_cap + 1,) is the number of slots per source vertex (the
+    sentinel row counting the dead slots); the per-vertex values reach the
+    sorted slots through it (``spread_to_slots``).  Callers derive it from
+    their own slot list; without it, it is counted from ``src``.
+    """
+    e = src.shape[0]
+    if counts is None:
+        count_trace("scan.counts_bincount")
+        counts = jnp.zeros((n_cap + 1,), jnp.int32).at[src].add(1)
+
+    s_src, s_c, s_w, k_i_to_c = _scan_communities_slots(src, dst, w, comm)
+    c_own, k_s, sigma_own, front_s = spread_to_slots(
+        counts, e, comm, k, sigma[comm], frontier)
+    # K_{i -> own community}: the own group's slots, self loops at 0, in
+    # the same stable relative order as the unsorted list.
+    k_to_own = jax.ops.segment_sum(
+        jnp.where(s_c == c_own, s_w, 0.0), s_src, num_segments=n_cap + 1)
+    (k_to_own_s,) = spread_to_slots(counts, e, k_to_own)
+    dq = delta_modularity(k_i_to_c, k_to_own_s, k_s, sigma[s_c], sigma_own, m)
+    valid = (s_c != c_own) & (s_src != n_cap) & (s_c != n_cap) & front_s
     dq = jnp.where(valid, dq, _NEG_INF)
 
     best_dq = jax.ops.segment_max(dq, s_src, num_segments=n_cap + 1)
     best_dq = jnp.where(jnp.isfinite(best_dq), best_dq, _NEG_INF)
-    is_best = (dq == best_dq[s_src]) & valid
+    (best_dq_s,) = spread_to_slots(counts, e, best_dq)
+    is_best = (dq == best_dq_s) & valid
     best_c = jax.ops.segment_min(
         jnp.where(is_best, s_c, n_cap), s_src, num_segments=n_cap + 1
     )
@@ -111,7 +169,8 @@ def best_moves(
 ) -> Tuple[jax.Array, jax.Array]:
     """Per-vertex (best community, best dQ) from one snapshot (sort-reduce path)."""
     return best_moves_slots(graph.src, graph.indices, graph.weights, comm,
-                            sigma, k, frontier, m, graph.n_cap)
+                            sigma, k, frontier, m, graph.n_cap,
+                            slot_counts(graph))
 
 
 def gather_frontier_slots(
@@ -172,7 +231,8 @@ def compact_best_moves(
 
     def compact_scan(_):
         return best_moves_slots(c_src, c_dst, c_w, comm, sigma, k, frontier,
-                                m, graph.n_cap)
+                                m, graph.n_cap,
+                                slot_counts(graph, frontier, work_cap))
 
     best_c, best_dq = jax.lax.cond(overflow, full_scan, compact_scan,
                                    operand=None)
@@ -197,9 +257,10 @@ class SortReduceScanner(ReplicatedScannerBase):
 
     def mark_neighbors(self, moved: jax.Array) -> jax.Array:
         g = self.graph
-        marked = jax.ops.segment_max(
-            moved[g.src].astype(jnp.int32), g.indices,
-            num_segments=g.n_cap + 1)
+        (moved_s,) = spread_to_slots(slot_counts(g), g.e_cap,
+                                     moved.astype(jnp.int32))
+        marked = jax.ops.segment_max(moved_s, g.indices,
+                                     num_segments=g.n_cap + 1)
         return marked > 0
 
 
